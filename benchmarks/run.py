@@ -88,6 +88,9 @@ def main() -> None:
                     help="sc_gemm trajectory file (default: repo root)")
     args = ap.parse_args()
 
+    from repro.launch import setup_compile_cache
+    setup_compile_cache()
+
     from . import fig1b, roofline, sc_gemm, table2
     suites = {"table2": table2.run, "fig1b": fig1b.run,
               "sc_gemm": lambda: sc_gemm.run(smoke=args.smoke),

@@ -578,6 +578,9 @@ def main() -> None:
     ap.add_argument("--arch", default="smollm-360m")
     args = ap.parse_args()
 
+    from repro.launch import setup_compile_cache
+    setup_compile_cache()
+
     rows = run(smoke=args.smoke, arch=args.arch)
     print("name,us_per_call,ttft_p50_ms,itl_p50_ms,derived")
     for row in rows:

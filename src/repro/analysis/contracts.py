@@ -50,7 +50,7 @@ _HALF = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16))
 # --------------------------------------------------------------- jaxpr walk
 
 def _subjaxprs(val: Any) -> Iterator[Any]:
-    from jax import core
+    from jax.extend import core
     if isinstance(val, core.Jaxpr):
         yield val
     elif isinstance(val, core.ClosedJaxpr):

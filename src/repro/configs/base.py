@@ -76,12 +76,11 @@ class ModelConfig:
     # shape/backend qualify (TPU, causal, no window/softcap, 128-aligned),
     # "jnp" forces the XLA formulation, "pallas_tuned" forces the kernel.
     attn_kernel: str = "auto"
-    # Paged decode-attention execution (DESIGN.md §9), resolved like
-    # attn_kernel: "auto" walks block tables in-kernel on TPU when the
-    # layout qualifies (GQA heads, no softcap, aligned extents), "jnp"
-    # forces the per-layer gathered-dense formulation, "pallas_tuned"
-    # forces the kernel on every eligible call regardless of backend
-    # (interpret mode off TPU — used by the bit-identity tests).
+    # Paged decode-attention execution (DESIGN.md §9): "auto" and "jnp"
+    # take the per-layer gathered-dense formulation; "pallas_tuned" walks
+    # block tables in-kernel on every eligible call in interpret mode (the
+    # bit-identity tests). The kernel does not lower for a compiled TPU, so
+    # there every mode gathers (models.layers._paged_kernel_eligible).
     paged_attn_kernel: str = "auto"
     # Self-speculative decoding (DESIGN.md §14): draft k tokens per round
     # through the SC popcount path at ``draft_bits`` operand width (same

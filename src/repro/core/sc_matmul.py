@@ -27,6 +27,7 @@ Three implementations, all bit-identical:
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
@@ -51,6 +52,11 @@ SC_IMPLS = ("auto", "ref", "reference", "mxu_split", "pallas", "pallas_tuned")
 #: Environment override consulted by :func:`resolve_impl` when the config
 #: leaves the choice open (``"auto"``/None).
 IMPL_ENV = "REPRO_SC_IMPL"
+
+#: Notes of the impl each :func:`sc_matmul` problem shape resolved to: INFO
+#: records with args ``(site, impl)``. Under ``jax.jit`` they are written at
+#: trace time, so they name what the compiled program runs.
+_dispatch_log = logging.getLogger("repro.dispatch")
 
 
 def _signed_counts_block(sx, mx, sy, my, bits: int) -> jax.Array:
@@ -215,11 +221,12 @@ def sc_matmul(a: jax.Array, b: jax.Array, *, bits: int = 8,
     sets it so inference is batch-composition invariant.
     """
     impl = resolve_impl(impl)
+    m, k = a.shape
+    _, n = b.shape
     if impl == "auto":
         from repro.kernels.autotune import choose_impl
-        m, k = a.shape
-        _, n = b.shape
         impl = choose_impl(m, k, n, bits=bits)
+    _dispatch_log.info("%s: %s", f"sc_matmul {m}x{k}x{n} b{bits}", impl)
     if impl in ("ref", "reference"):
         return sc_matmul_reference(a, b, bits=bits, row_quant=row_quant)
     if impl == "mxu_split":

@@ -82,8 +82,10 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 #: variant segment (``:sc<bits>``) to the flash and paged key grammars —
 #: a v4 winner was swept on the float contraction only and must not serve
 #: the SC path (or vice versa). Older documents are *invalidated* on load
-#: (not migrated); affected shapes simply re-tune once.
-CACHE_VERSION = 5
+#: (not migrated); affected shapes simply re-tune once. v6 follows the
+#: SC-GEMM kernel's move to a transposed LHS (M on lanes, K on sublanes),
+#: which changed what every block configuration costs.
+CACHE_VERSION = 6
 
 #: VMEM budget used to prune candidates; conservative fraction of ~16 MiB.
 VMEM_BUDGET_BYTES = 12 * 2 ** 20
@@ -125,12 +127,19 @@ class KernelConfig:
     chunk: int = 8
 
     def vmem_bytes(self) -> int:
-        """Estimated VMEM working set of one grid step (DESIGN.md §2.2)."""
-        lhs = 2 * self.bm * self.bk          # sx, mx
-        rhs = 4 * self.bk * self.bn          # sy, my, msb, y_low
-        out = 2 * self.bm * self.bn          # acc scratch + out tile
-        bcast = 2 * self.bm * self.chunk * self.bn   # residual r and s
-        return 4 * (lhs + rhs + out + bcast)
+        """Estimated VMEM working set of one grid step (DESIGN.md §2.2).
+
+        The LHS planes enter transposed, ``(bk, bm)``, so ``bm`` lies on
+        lanes and pads to a multiple of 128 there. Inputs and the output
+        are double-buffered by the Pallas pipeline; the MXU term's loaded
+        and cast operands are temporaries of the input tiles' size."""
+        lanes_m = _round_up(self.bm, 128)
+        rows_m = _round_up(self.bm, 8)
+        lhs = 2 * 2 * self.bk * lanes_m          # sxᵀ, mxᵀ, double-buffered
+        rhs = 2 * 2 * self.bk * self.bn          # sy, my, double-buffered
+        out = 3 * rows_m * self.bn               # out (x2) + acc scratch
+        temps = 2 * self.bk * (lanes_m + self.bn)
+        return 4 * (lhs + rhs + out + temps)
 
     def is_valid(self) -> bool:
         return (self.bm % 8 == 0 and self.bn % 128 == 0 and
@@ -375,32 +384,28 @@ def candidate_configs(m: int, k: int, n: int, *,
     """Pruned SC-GEMM tuning grid for an (M, K, N) problem.
 
     Blocks larger than the (128-aligned) problem extent only add padding
-    work, so they are dropped; every candidate satisfies the VMEM budget and
-    chunk | bk. Skinny (decode-shaped, M ≤ SKINNY_M_MAX) problems add
-    GEMV-like bm candidates ahead of the default 128 tile — a decode step's
-    M is the live batch, and a 128-row tile is ≥ 2x padding waste there.
+    work, so they are dropped (the 128 block always stays); every candidate
+    satisfies the VMEM budget and chunk | bk. Skinny (decode-shaped,
+    M ≤ SKINNY_M_MAX) problems get one GEMV-like bm, the bucket itself: M
+    lies on the kernel's lanes, where any larger block is pure padding.
+    The residual chunk is one sublane tile (8 int32 rows).
     """
     m_cap = _round_up(max(m, 8), 128)
     n_cap = _round_up(max(n, 128), 128)
     k_cap = _round_up(max(k, 128), 128)
-    bm_options: tuple[int, ...] = (128, 256)
-    if m <= SKINNY_M_MAX:
-        skinny = tuple(b for b in (8, 16, 32, 64) if b >= bucket_m(m))
-        bm_options = skinny + bm_options
+    bm_options = ((bucket_m(m),) if m <= SKINNY_M_MAX
+                  else tuple(b for b in (128, 256) if b <= m_cap))
     out: list[KernelConfig] = []
     for bm in bm_options:
-        if bm > m_cap and bm != 128:
-            continue
-        for bn in (128, 256):
+        for bn in (128, 256, 512):
             if bn > n_cap and bn != 128:
                 continue
             for bk in (128, 256, 512):
                 if bk > k_cap and bk != 128:
                     continue
-                for chunk in (4, 8, 16):
-                    cfg = KernelConfig(bm=bm, bn=bn, bk=bk, chunk=chunk)
-                    if cfg.is_valid() and cfg.vmem_bytes() <= vmem_budget:
-                        out.append(cfg)
+                cfg = KernelConfig(bm=bm, bn=bn, bk=bk, chunk=8)
+                if cfg.is_valid() and cfg.vmem_bytes() <= vmem_budget:
+                    out.append(cfg)
     return out
 
 
@@ -505,9 +510,8 @@ def _sweep_outside_trace(fn: Callable[[], tuple]):
 
     JAX's trace context is thread-local, so a fresh worker thread sees no
     active trace: the sweep's (concrete, synthetic) operands execute eagerly
-    instead of leaking into the caller's jaxpr — and the Pallas kernel
-    tracing inside the timed calls is not corrupted by the caller's dynamic
-    trace (``ensure_compile_time_eval`` is not enough for that on jax 0.4).
+    instead of leaking into the caller's jaxpr, and the Pallas kernel
+    tracing inside the timed calls never sees the caller's dynamic trace.
     """
     import concurrent.futures
 

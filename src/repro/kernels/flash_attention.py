@@ -23,7 +23,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.errors import ConfigError
 
-from ._compat import CompilerParams as _CompilerParams
 from .sc_attention import sc_pv, sc_scores
 
 __all__ = ["flash_attention_pallas"]
@@ -126,7 +125,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # l
             pltpu.VMEM((bq, d), jnp.float32),     # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

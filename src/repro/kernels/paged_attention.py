@@ -72,7 +72,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.errors import ConfigError
 
-from ._compat import CompilerParams as _CompilerParams
 from .sc_attention import sc_pv, sc_scores
 
 __all__ = ["paged_attention_pallas"]
@@ -264,7 +263,7 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, kv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables.astype(jnp.int32), q_positions.astype(jnp.int32),

@@ -69,10 +69,8 @@ def generate(cfg, params, prompts: jnp.ndarray, *, gen_tokens: int,
     return jnp.stack(out, axis=1)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     from repro.core.sc_matmul import SC_IMPLS
-    from repro.launch import apply_numeric_overrides
-    from repro.serving import Engine, Request
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
@@ -155,7 +153,14 @@ def main() -> None:
                     help="drive the engine through per-request token "
                          "callbacks and print an SSE-style event feed as "
                          "tokens land, instead of waiting for run() to drain")
-    args = ap.parse_args()
+    return ap
+
+
+def build_config(args: argparse.Namespace):
+    """The served model config the parsed flags describe."""
+    import dataclasses
+
+    from repro.launch import apply_numeric_overrides
 
     cfg = ARCHS[args.arch]
     if args.reduced:
@@ -163,17 +168,20 @@ def main() -> None:
     cfg = apply_numeric_overrides(cfg, sc_gemm=args.sc_gemm,
                                   sc_impl=args.sc_impl)
     if args.paged_attn is not None:
-        import dataclasses
         cfg = dataclasses.replace(cfg,
                                   paged_attn_kernel=args.paged_attn).validate()
     if args.attn_sc or args.attn_sc_bits is not None:
-        import dataclasses
         over = {"attn_sc": True}
         if args.attn_sc_bits is not None:
             over["sc_bits"] = args.attn_sc_bits
         cfg = dataclasses.replace(cfg, **over).validate()
-    m = bind(cfg)
-    params = m.init_params(jax.random.PRNGKey(0))
+    return cfg
+
+
+def build_requests(cfg, args: argparse.Namespace) -> list:
+    """The synthetic workload: ``args.requests`` prompts of
+    ``args.prompt_len`` tokens, new-token budgets mixed in [gen/4, gen]."""
+    from repro.serving import Request
 
     rng = np.random.default_rng(1)
 
@@ -194,18 +202,35 @@ def main() -> None:
                 max_new_tokens=int(g), temperature=args.temperature, seed=i)
         for i, g in enumerate(gens)
     ]
+    return requests
 
-    engine = Engine(cfg, params, capacity=args.capacity,
-                    max_seq=args.prompt_len + args.gen,
-                    continuous=not args.no_continuous,
-                    paged=not args.no_paged, block=args.block,
-                    n_blocks=args.pages, fused=not args.no_fused_paged,
-                    prefill_mode=args.prefill_mode, chunk=args.chunk,
-                    prefill_budget=args.prefill_budget,
-                    prefix_cache=args.prefix_cache,
-                    prefix_hash_seed=args.prefix_block_hash,
-                    speculate_k=args.speculate_k,
-                    draft_bits=args.draft_bits)
+
+def build_engine(cfg, params, args: argparse.Namespace):
+    """The serving engine the parsed flags describe, over ``params``."""
+    from repro.serving import Engine
+
+    return Engine(cfg, params, capacity=args.capacity,
+                  max_seq=args.prompt_len + args.gen,
+                  continuous=not args.no_continuous,
+                  paged=not args.no_paged, block=args.block,
+                  n_blocks=args.pages, fused=not args.no_fused_paged,
+                  prefill_mode=args.prefill_mode, chunk=args.chunk,
+                  prefill_budget=args.prefill_budget,
+                  prefix_cache=args.prefix_cache,
+                  prefix_hash_seed=args.prefix_block_hash,
+                  speculate_k=args.speculate_k,
+                  draft_bits=args.draft_bits)
+
+
+def main() -> None:
+    from repro.launch import setup_compile_cache
+
+    args = build_parser().parse_args()
+    setup_compile_cache()
+    cfg = build_config(args)
+    params = bind(cfg).init_params(jax.random.PRNGKey(0))
+    requests = build_requests(cfg, args)
+    engine = build_engine(cfg, params, args)
     t0 = time.time()
     if args.stream:
         # SSE-style feed: one `data:` line per emitted token, as it lands

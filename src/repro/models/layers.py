@@ -12,6 +12,7 @@ M-RoPE (qwen2-vl), QKV bias (qwen2).
 from __future__ import annotations
 
 import functools
+import logging
 from typing import NamedTuple
 
 import jax
@@ -78,6 +79,13 @@ def apply_mrope(x: jax.Array, positions: jax.Array, sections: tuple[int, ...],
         start += sec
     ang = jnp.concatenate(parts, axis=-1)                      # (B, S, half)
     return apply_rope(x, jnp.cos(ang), jnp.sin(ang))
+
+
+#: Trace-time notes of the path each attention call site took: INFO records
+#: with args ``(site, "<path>: <why>")``. Written when a step is traced, so
+#: they describe what the compiled programs run; ``chip_smoke.py`` prints
+#: them.
+_dispatch_log = logging.getLogger("repro.dispatch")
 
 
 class _FlashCarry(NamedTuple):
@@ -202,9 +210,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     eligible = canonical_positions and _flash_kernel_eligible(
         sq, skv, d, causal=causal, window=window,
         logit_softcap=logit_softcap, bf16_probs=bf16_probs, sc_bits=sc_bits)
+    backend = jax.default_backend()
     use_kernel = (kernel_impl == "pallas_tuned" and eligible) or (
-        kernel_impl == "auto" and eligible
-        and jax.default_backend() == "tpu")
+        kernel_impl == "auto" and eligible and backend == "tpu")
+    if kernel_impl == "jnp":
+        why = "kernel_impl='jnp'"
+    elif not canonical_positions:
+        why = "explicit positions (the kernel masks 0..S-1 only)"
+    elif not eligible:
+        why = (f"outside the kernel envelope (causal={causal}, "
+               f"window={window}, softcap={logit_softcap}, "
+               f"bf16_probs={bf16_probs}, S={sq}/{skv}, d={d}; needs "
+               f"causal self-attention with S, d multiples of 128)")
+    elif not use_kernel:
+        why = f"kernel_impl='auto' on backend {backend!r}"
+    else:
+        why = f"kernel_impl={kernel_impl!r}, eligible"
+    _dispatch_log.info(
+        "%s: %s", f"flash sc{sc_bits or 0} B{b} S{sq}/{skv} H{h}/{kv_heads} "
+        f"d{d}", f"{'pallas' if use_kernel else 'jnp'}: {why}")
     if use_kernel:
         return _flash_kernel_call(q, k, v, q_block, kv_block,
                                   skip_masked_blocks, sc_bits)
@@ -353,9 +377,7 @@ def _paged_kernel_eligible(g: int, d: int, block: int,
     (g ≥ 2, per-page score tiles) and — via the whole-row finish einsum —
     full-MHA (g == 1, which needs kvh ≥ 2 per grid step and therefore
     kv ≥ 2); no logit softcap (the tanh chain fuses differently per
-    program). Compiled TPU additionally needs MXU/sublane-aligned extents;
-    interpret mode executes the same jnp ops and has no alignment
-    constraint. The tuning grid must also be non-empty — single-KV-head
+    program). The tuning grid must also be non-empty — single-KV-head
     full-MHA has no kvh ≥ 2 split, and a whole-row scratch too big for
     the VMEM budget (huge ``max_blocks · block``) has no valid candidate;
     either way the dispatch must fall back to the gather rather than let
@@ -365,10 +387,18 @@ def _paged_kernel_eligible(g: int, d: int, block: int,
     contraction has no einsum lowering sensitivity, so every head layout —
     including single-KV-head full-MHA — stays bit-identical and the
     candidate grid keeps ``kvh = 1``. Softcap remains out (same tanh-fusion
-    drift as the float path)."""
-    if logit_softcap is not None or not sc_attention_bits_ok(sc_bits):
+    drift as the float path).
+
+    Interpret mode only. Compiled for TPU, Mosaic refuses the kernel at
+    every layout: with ``kvh < KV`` the ``(1, block, kvh, d)`` block over
+    the ``(P, block, KV, D)`` pool breaks the (8, 128) tiling rule; with
+    ``kvh == KV`` the float path fails with "'tpu.matmul' op Not
+    implemented: Up to 1 batch dim supported" (its 5-D einsums) and the SC
+    path with "infer-vector-layout: unsupported shape cast" (its
+    reshapes). So a compiled backend always takes the gathered path."""
+    if not interpret:
         return False
-    if not (interpret or (d % 128 == 0 and block % 8 == 0)):
+    if logit_softcap is not None or not sc_attention_bits_ok(sc_bits):
         return False
     from repro.kernels.autotune import candidate_paged_configs
     return bool(candidate_paged_configs(kv, g, d, block=block,
@@ -385,14 +415,12 @@ def paged_decode_attention(q: jax.Array, paged: PagedKV, *,
     """Single-step attention straight against the paged KV pool.
 
     ``q: (C, 1, H, D)``; ``paged`` holds this site's page pools and block
-    table; ``q_position: (C,)``. ``kernel_impl`` dispatches like
-    ``flash_attention``'s (DESIGN.md §6): "auto" walks the block table
-    in-kernel on TPU when :func:`_paged_kernel_eligible` holds,
-    "pallas_tuned" forces the kernel on every eligible call regardless of
-    backend (interpret off TPU — the bit-identity tests), "jnp" forces the
-    gathered-dense formulation. Ineligible calls (softcap layers,
-    single-KV-head full-MHA) always gather — per layer, never the whole
-    cache tree.
+    table; ``q_position: (C,)``. :func:`_paged_kernel_eligible` holds in
+    interpret mode only, and only ``kernel_impl="pallas_tuned"`` takes the
+    kernel there (the bit-identity tests); "auto" and "jnp" take the
+    gathered-dense formulation. Ineligible calls (compiled backends,
+    softcap layers, single-KV-head full-MHA) always gather — per layer,
+    never the whole cache tree.
     """
     if kernel_impl not in ("auto", "jnp", "pallas_tuned"):
         raise ValueError(f"unknown paged attention kernel_impl "
@@ -406,9 +434,23 @@ def paged_decode_attention(q: jax.Array, paged: PagedKV, *,
                                       interpret, kv=kv,
                                       max_blocks=paged.tables.shape[1],
                                       sc_bits=sc_bits)
-    use_kernel = (kernel_impl == "pallas_tuned" and eligible) or (
-        kernel_impl == "auto" and eligible
-        and jax.default_backend() == "tpu")
+    use_kernel = kernel_impl == "pallas_tuned" and eligible
+    if kernel_impl == "jnp":
+        why = "kernel_impl='jnp'"
+    elif not interpret:
+        why = "compiled backend: the paged kernel does not lower with Mosaic"
+    elif not eligible:
+        why = (f"outside the bit-identity envelope (g={g}, kv={kv}, "
+               f"softcap={logit_softcap}, sc_bits={sc_bits}, or no "
+               f"tuning candidate fits VMEM)")
+    elif not use_kernel:
+        why = "kernel_impl='auto' (only 'pallas_tuned' takes the kernel)"
+    else:
+        why = "kernel_impl='pallas_tuned' in interpret mode"
+    _dispatch_log.info(
+        "%s: %s", f"paged sc{sc_bits or 0} C{c} H{h}/{kv} d{d} "
+        f"blk{paged.block}x{paged.tables.shape[1]}",
+        f"{'pallas' if use_kernel else 'gather'}: {why}")
     if use_kernel:
         from repro.kernels.ops import paged_decode_attention_tuned
         out = paged_decode_attention_tuned(

@@ -21,16 +21,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_forward"]
 
-# jax.shard_map (with check_vma) only exists on newer jax; 0.4.x ships it as
-# jax.experimental.shard_map.shard_map with the check_rep spelling.
-try:
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
 def pipeline_forward(stage_fn: Callable, stage_params, x: jax.Array, *,
                      mesh: Mesh, axis: str = "stage",
                      n_microbatches: int) -> jax.Array:
@@ -81,11 +71,11 @@ def pipeline_forward(stage_fn: Callable, stage_params, x: jax.Array, *,
         return outputs
 
     shard = functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **{_CHECK_KW: False})
+        check_vma=False)
 
     outputs = shard(per_stage)(stage_params, micro)
     return outputs.reshape(b, *x.shape[1:])

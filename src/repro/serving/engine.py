@@ -276,6 +276,7 @@ class Engine:
         self._prefill_shapes: set[tuple[int, int]] = set()
         self._last_decode_end: float | None = None
         self._max_decode_gap = 0.0
+        self._n_nonfinite_rows = 0      # host-sampled rows with NaN/inf
         self._n_prefix_hits = 0
         self._n_prefix_misses = 0
         self._prefill_tokens_saved = 0
@@ -325,8 +326,12 @@ class Engine:
         chain (seeded by the request, split once per emitted token), so a
         stream is a function of the request alone — which slot or engine
         step produced it is irrelevant (and a preempted, restarted request
-        regenerates the identical stream).
+        regenerates the identical stream). A row holding NaN or inf is
+        counted (``stats["nonfinite_logit_rows"]``): argmax over it still
+        returns an id, so without the count the fault would be silent.
         """
+        if not np.isfinite(row).all():
+            self._n_nonfinite_rows += 1
         req = entry.request
         if req.temperature <= 0:
             return np.argmax(row, axis=-1).astype(np.int32)
@@ -822,6 +827,7 @@ class Engine:
         steps0, prefills0 = self._step, self._n_prefills
         chunks0, preempt0 = self._n_prefill_chunks, self._n_preemptions
         hits0, misses0 = self._n_prefix_hits, self._n_prefix_misses
+        nonfinite0 = self._n_nonfinite_rows
         saved0 = self._prefill_tokens_saved
         cow0 = getattr(self.pool, "n_cow", 0)
         reclaim0 = getattr(self.pool, "n_reclaimed", 0)
@@ -862,6 +868,7 @@ class Engine:
             "prefills": self._n_prefills - prefills0,
             "prefill_chunks": self._n_prefill_chunks - chunks0,
             "preemptions": self._n_preemptions - preempt0,
+            "nonfinite_logit_rows": self._n_nonfinite_rows - nonfinite0,
             "wall_s": wall,
             "tok_per_s": generated / wall if wall > 0 else float("inf"),
             "p50_latency_s": pctl(lats, 0.5),

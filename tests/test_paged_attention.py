@@ -198,8 +198,14 @@ def test_kernel_softcap_close_but_gated():
     # a whole-row scratch past the VMEM budget has no tuning candidate —
     # the gate must route it to the gather instead of letting the tuner
     # raise "no tuning candidates" inside a jitted decode step
-    assert not _paged_kernel_eligible(4, 128, 16, None, False, kv=8,
+    assert _paged_kernel_eligible(4, 128, 16, None, True, kv=8,
+                                  max_blocks=4)
+    assert not _paged_kernel_eligible(4, 128, 16, None, True, kv=8,
                                       max_blocks=2048)
+    # compiled (non-interpret) backends never take the kernel: Mosaic
+    # refuses it at every layout, so even an aligned GQA layout gathers
+    assert not _paged_kernel_eligible(4, 128, 16, None, False, kv=8,
+                                      max_blocks=4)
 
 
 def test_kernel_rejects_non_dividing_kvh():
