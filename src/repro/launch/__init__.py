@@ -37,7 +37,10 @@ def setup_compile_cache() -> str:
         cache_dir = str(REPO_ROOT / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    os.environ.setdefault("TPU_LOG_DIR", os.path.join(cache_dir, "tpu_logs"))
+    if "TPU_LOG_DIR" not in os.environ:
+        # the runtime makes only the last component of a missing path
+        os.environ["TPU_LOG_DIR"] = os.path.join(cache_dir, "tpu_logs")
+        os.makedirs(os.environ["TPU_LOG_DIR"], exist_ok=True)
     os.environ.setdefault(CACHE_ENV,
                           os.path.join(cache_dir, "sc_gemm_autotune.json"))
     return cache_dir

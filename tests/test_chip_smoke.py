@@ -134,13 +134,14 @@ def test_compile_cache_location(monkeypatch, tmp_path, undo_cache_setup,
     from repro.launch import REPO_ROOT, setup_compile_cache
 
     if from_env:
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        # a directory that does not exist yet, two levels deep
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "a/b"))
     else:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     got = setup_compile_cache()
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
     if from_env:
-        assert got == str(tmp_path)
+        assert got == str(tmp_path / "a/b")
         assert (jax.config.jax_compilation_cache_dir
                 == undo_cache_setup["jax_compilation_cache_dir"])
     else:
@@ -149,6 +150,7 @@ def test_compile_cache_location(monkeypatch, tmp_path, undo_cache_setup,
         assert ".jax_cache/" in (REPO_ROOT / ".gitignore").read_text()
     assert os.environ[CACHE_ENV] == os.path.join(got, "sc_gemm_autotune.json")
     assert os.environ["TPU_LOG_DIR"] == os.path.join(got, "tpu_logs")
+    assert os.path.isdir(os.environ["TPU_LOG_DIR"])
 
 
 def test_main_refuses_without_a_tpu(capsys, undo_cache_setup):

@@ -20,13 +20,14 @@ from repro.kernels.sc_bitops import sc_stream_mul_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def one_chip(tmp_path_factory):
     """One chip of a described v5e:2x2. Its tests compile with 64-bit types
     off, as the chip runs (the suite's conftest turns them on for exact
-    error sweeps)."""
+    error sweeps). The TPU library writes its logs under ``/tmp`` unless
+    ``$TPU_LOG_DIR`` names a directory ("disabled" does not stop it)."""
     from jax.experimental import topologies
     with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TPU_LOG_DIR", "disabled")
+        mp.setenv("TPU_LOG_DIR", str(tmp_path_factory.mktemp("tpu_logs")))
         try:
             topo = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
