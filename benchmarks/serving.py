@@ -26,9 +26,9 @@ A gate-exempt marker row records the **chunked-vs-one-shot prefill A/B**
 (ISSUE 6 / DESIGN.md §10) on a varied-prompt-length workload: one-shot
 admission stalls the whole decode batch for a full-prompt forward, while
 chunked prefill bounds the worst gap between consecutive decode steps to
-roughly one chunk — the row reports both ``max_decode_gap`` numbers, and
-asserts that both modes generate bit-identical streams and that the
-prompt-bucket set bounds the number of chunked-prefill executables
+roughly one chunk (the ``engine.step`` spans of a profiler trace show
+it). The row asserts that both modes generate bit-identical streams and
+that the prompt-bucket set bounds the number of chunked-prefill executables
 (``prefill_executables <= len(buckets)``), so the smoke CI job fails if
 bucketing ever starts compiling per prompt length.
 
@@ -245,8 +245,6 @@ def _speculative_row(cfg, params, mesh, n: int, capacity: int,
             f" tok_per_round={st['spec_tokens_per_round']:.2f}"
             f" rounds={st['spec_rounds']}"
             f" base_decode_steps={stats['baseline']['decode_steps']}"
-            f" draft_us={st['spec_draft_us']:.0f}"
-            f" verify_us={st['spec_verify_us']:.0f}"
             f" requests={n} capacity={capacity}"),
     }
 
@@ -383,9 +381,7 @@ def _chunked_row(cfg, params, mesh, capacity: int, prompt_len: int,
     """Chunked-vs-one-shot prefill marker (gate-exempt): a varied-length
     long-prompt workload where one-shot admission stalls every live decode
     slot for a whole-prompt forward, while chunked prefill interleaves —
-    at most one chunk of prefill per decode step. ``max_decode_gap`` (the
-    worst wall-clock gap between consecutive decode-step completions) is
-    the stall each mode imposes on co-batched streams. Hard-asserted, not
+    at most one chunk of prefill per decode step. Hard-asserted, not
     timed: both modes emit bit-identical streams, and the chunked
     executable count stays bounded by the bucket set even though the
     workload has more distinct prompt lengths than buckets get used."""
@@ -427,10 +423,7 @@ def _chunked_row(cfg, params, mesh, capacity: int, prompt_len: int,
         "name": f"serving/chunked_prefill/{cfg.name}",
         "us_per_call": 0.0,
         "derived": (
-            f"chunked_gap_ms={st['max_decode_gap_s'] * 1e3:.1f}"
-            f" oneshot_gap_ms="
-            f"{stats['oneshot']['max_decode_gap_s'] * 1e3:.1f}"
-            f" chunk={st['chunk']}"
+            f"chunk={st['chunk']}"
             f" prefill_chunks={st['prefill_chunks']}"
             f" executables={st['prefill_executables']}"
             f"/{len(st['buckets'])}buckets"

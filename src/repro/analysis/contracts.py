@@ -271,9 +271,10 @@ def audit_cow_protocol() -> list[str]:
         step_ix += 1
         check_step(step_ix)
 
-    if engine._n_prefix_hits < 2:
+    hits = engine.counters()["prefix_hits"]
+    if hits < 2:
         problems.append(
-            f"cow protocol: schedule produced {engine._n_prefix_hits} "
+            f"cow protocol: schedule produced {hits} "
             f"prefix hits — the audit never exercised sharing")
     if pool.n_cow < 1:
         problems.append(

@@ -258,9 +258,7 @@ def main() -> None:
     if st.get("speculative"):
         pages += (f", spec k={st['speculate_k']}@{st['draft_bits']}b: "
                   f"{st['spec_acceptance_rate']:.0%} accepted, "
-                  f"{st['spec_tokens_per_round']:.2f} tok/round "
-                  f"(draft {st['spec_draft_us']:.0f}us "
-                  f"verify {st['spec_verify_us']:.0f}us)")
+                  f"{st['spec_tokens_per_round']:.2f} tok/round")
     print(f"[serve] {st['mode']}/{st['layout']}/{st['prefill_mode']}: "
           f"{st['requests']} requests, "
           f"{st['generated_tokens']} tokens in {dt:.1f}s "
@@ -269,8 +267,7 @@ def main() -> None:
           f"p50 {st['p50_latency_s'] * 1e3:.0f}ms "
           f"p99 {st['p99_latency_s'] * 1e3:.0f}ms, "
           f"ttft p50 {st['ttft_p50_s'] * 1e3:.0f}ms "
-          f"itl p50 {st['itl_p50_s'] * 1e3:.1f}ms, "
-          f"max decode gap {st['max_decode_gap_s'] * 1e3:.0f}ms "
+          f"itl p50 {st['itl_p50_s'] * 1e3:.1f}ms "
           f"({st['prefill_chunks']} prefill chunks, "
           f"{st['prefill_executables']} executables / "
           f"{len(st['buckets'])} buckets){pages}")

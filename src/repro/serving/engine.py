@@ -60,6 +60,14 @@ exactly), attention K/V rows are per-row computations scattered at
 absolute positions, and the bucket's padding columns are causally masked
 into exact no-ops — the invariant tests/test_serving.py sweeps and
 tests/test_paging.py fuzzes.
+
+Observability: the work of a step is wrapped in ``engine.*`` host spans
+(``jax.profiler.TraceAnnotation``; a span is a cheap no-op unless a profiler
+trace is running, and then lands on the trace's host plane beside the
+device's operations), and :meth:`Engine.counters` returns cumulative totals
+that a caller snapshots between steps. ``engine.sync`` wraps every blocking
+``jax.device_get``: the only span in which the host waits on the device.
+The spans of one request carry its ``uid``.
 """
 from __future__ import annotations
 
@@ -85,6 +93,16 @@ from .queue import Request, RequestQueue, RequestResult
 from .slots import PagedSlotPool, PoolExhausted, SlotEntry, SlotPool
 
 __all__ = ["Engine", "default_serving_mesh"]
+
+span = jax.profiler.TraceAnnotation
+
+#: The totals :meth:`Engine.counters` keeps itself (the rest it reads from
+#: the pool and the queue).
+_COUNTS = ("steps", "decode_rows", "prefill_chunks", "prefill_rows",
+           "prefills", "prompt_tokens", "prefix_hits", "prefix_misses",
+           "prefill_tokens_saved", "preemptions", "nonfinite_logit_rows",
+           "spec_rounds", "spec_drafted_tokens", "spec_accepted_tokens",
+           "spec_emitted_tokens")
 
 #: ``on_token(uid, index, token, finished_reason)`` — ``index`` is the
 #: 0-based position in the generated stream; ``finished_reason`` is None
@@ -265,31 +283,42 @@ class Engine:
         self.queue = RequestQueue()
         self.stats: dict[str, Any] = {}
         self._step = 0          # decode-step counter (admissions are free)
-        self._n_prefills = 0
-        self._n_prefill_chunks = 0
-        self._n_preemptions = 0
+        # cumulative totals, see counters(); the decode steps are _step
+        self._counts = dict.fromkeys(_COUNTS, 0)
         self._admit_counter = 0
         self._staging: _StagingPrefill | None = None
         self._results: dict[str, RequestResult] = {}
         self._callbacks: dict[str, TokenCallback] = {}
         self._first_token_at: dict[str, float] = {}
         self._prefill_shapes: set[tuple[int, int]] = set()
-        self._last_decode_end: float | None = None
-        self._max_decode_gap = 0.0
-        self._n_nonfinite_rows = 0      # host-sampled rows with NaN/inf
-        self._n_prefix_hits = 0
-        self._n_prefix_misses = 0
-        self._prefill_tokens_saved = 0
-        self._n_spec_rounds = 0
-        self._spec_drafted = 0          # draft tokens proposed (live slots)
-        self._spec_draft_accepted = 0   # draft tokens verification kept
-        self._spec_emitted = 0          # tokens emitted by spec rounds
-        self._spec_draft_s = 0.0
-        self._spec_verify_s = 0.0
         self._backpressure: dict[str, list[dict]] = {"admission": [],
                                                      "decode": []}
 
     # ------------------------------------------------------------ plumbing
+
+    def counters(self) -> dict:
+        """Cumulative totals since construction, as plain numbers; the
+        difference of two snapshots taken between steps covers the steps in
+        between.
+
+        ``steps`` (:meth:`step` calls that had work), ``decode_steps`` (a
+        speculative round counts as one) and ``decode_rows`` (live slots
+        summed over them); ``prefill_chunks`` and ``prefill_rows`` (valid
+        prompt tokens they computed); ``prefills`` and ``prompt_tokens``
+        (requests admitted and their prompt lengths); ``prefix_hits``,
+        ``prefix_misses`` and ``prefill_tokens_saved`` (prompt tokens a
+        prefix hit seeded instead of computing); ``preemptions``,
+        ``cow_copies``, ``prefix_reclaims`` and ``nonfinite_logit_rows``;
+        ``queue_pops`` and ``queue_wait_s`` (seconds from ``submit`` or a
+        preemption's requeue to the pop that starts the request's prefill,
+        summed); and the speculative ``spec_rounds``,
+        ``spec_drafted_tokens``, ``spec_accepted_tokens`` and
+        ``spec_emitted_tokens``."""
+        return {**self._counts, "decode_steps": self._step,
+                "cow_copies": getattr(self.pool, "n_cow", 0),
+                "prefix_reclaims": getattr(self.pool, "n_reclaimed", 0),
+                "queue_pops": self.queue.pops,
+                "queue_wait_s": self.queue.wait_s}
 
     @property
     def has_work(self) -> bool:
@@ -317,7 +346,8 @@ class Engine:
         self._prefill_shapes.add((req.prompt_len, 0))
         batch = {"tokens": jnp.asarray(req.prompt)[None]}
         logits, cache = prefill(self._params, batch)
-        return np.asarray(jax.device_get(logits))[0, -1], cache
+        with span("engine.sync"):
+            return np.asarray(jax.device_get(logits))[0, -1], cache
 
     def _sample(self, entry: SlotEntry, row: np.ndarray) -> np.ndarray:
         """One token from a logit row ((V,) or (K, V) for codebooks).
@@ -331,7 +361,7 @@ class Engine:
         returns an id, so without the count the fault would be silent.
         """
         if not np.isfinite(row).all():
-            self._n_nonfinite_rows += 1
+            self._counts["nonfinite_logit_rows"] += 1
         req = entry.request
         if req.temperature <= 0:
             return np.argmax(row, axis=-1).astype(np.int32)
@@ -362,7 +392,8 @@ class Engine:
         if cb is not None:
             cb(uid, entry.n_generated - 1, tok, reason)
         if reason is not None:
-            self.pool.evict(slot)
+            with span("engine.evict", uid=uid):
+                self.pool.evict(slot)
             self._callbacks.pop(uid, None)
             req = entry.request
             self._results[uid] = RequestResult(
@@ -406,17 +437,18 @@ class Engine:
         self._admit_counter += 1
         match = None
         if self.prefix is not None:
-            plan = self.prefix.match(req.prompt)
-            if plan.hit:
-                match = plan
-                self.pool.pin_pages(plan.pages)
-                cache = cache_ops.prefix_seed(
-                    cache, self.pool.cache, plan.pages,
-                    block=self.pool.block, resume=plan.resume)
-                entry.prefill_offset = plan.resume
-                self._n_prefix_hits += 1
-            else:
-                self._n_prefix_misses += 1
+            with span("engine.prefix_match", uid=req.uid):
+                plan = self.prefix.match(req.prompt)
+                if plan.hit:
+                    match = plan
+                    self.pool.pin_pages(plan.pages)
+                    cache = cache_ops.prefix_seed(
+                        cache, self.pool.cache, plan.pages,
+                        block=self.pool.block, resume=plan.resume)
+                    entry.prefill_offset = plan.resume
+                    self._counts["prefix_hits"] += 1
+                else:
+                    self._counts["prefix_misses"] += 1
         return _StagingPrefill(entry=entry, bucket=bucket, step=step,
                                cache=cache, match=match)
 
@@ -432,9 +464,11 @@ class Engine:
                  "n_valid": jnp.asarray([nv], jnp.int32)}
         logits, st.cache = st.step(self._params, st.cache, batch)
         st.entry.prefill_offset = off + nv
-        self._n_prefill_chunks += 1
+        self._counts["prefill_chunks"] += 1
+        self._counts["prefill_rows"] += nv
         if st.done:
-            st.rows = np.asarray(jax.device_get(logits))[0, -1]
+            with span("engine.sync"):
+                st.rows = np.asarray(jax.device_get(logits))[0, -1]
 
     def _can_admit_staged(self, st: _StagingPrefill) -> bool:
         if not self.pool.has_free:
@@ -455,26 +489,28 @@ class Engine:
         st = self._staging
         self._staging = None
         req = st.entry.request
-        single = cache_ops.truncate_seq(st.cache, req.prompt_len)
-        st.entry.admitted_at = time.perf_counter()
-        st.entry.admit_step = self._step
-        if st.match is not None:
-            slot = self.pool.admit_prefix(st.entry, single, st.match)
-            if st.match.cow_src is not None:
-                self.pool.unpin_pages([st.match.cow_src])
-            # count the skipped span at admission, not staging start: a
-            # preempted staging prefill re-stages (and re-matches), so an
-            # early count would tally the same request's resume twice
-            self._prefill_tokens_saved += st.match.resume
-        else:
-            slot = self.pool.admit(st.entry, single)
-        if self.prefix is not None:
-            full = req.prompt_len // self.pool.block
-            new = self.prefix.insert(req.prompt,
-                                     self.pool.tables[slot, :full].tolist())
-            self.pool.retain_pages(new)
-        self._n_prefills += 1
-        self._emit(slot, st.entry, self._sample(st.entry, st.rows))
+        with span("engine.admit", uid=req.uid):
+            single = cache_ops.truncate_seq(st.cache, req.prompt_len)
+            st.entry.admitted_at = time.perf_counter()
+            st.entry.admit_step = self._step
+            if st.match is not None:
+                slot = self.pool.admit_prefix(st.entry, single, st.match)
+                if st.match.cow_src is not None:
+                    self.pool.unpin_pages([st.match.cow_src])
+                # count the skipped span at admission, not staging start: a
+                # preempted staging prefill re-stages (and re-matches), so
+                # an early count would tally the same request's resume twice
+                self._counts["prefill_tokens_saved"] += st.match.resume
+            else:
+                slot = self.pool.admit(st.entry, single)
+            if self.prefix is not None:
+                full = req.prompt_len // self.pool.block
+                new = self.prefix.insert(
+                    req.prompt, self.pool.tables[slot, :full].tolist())
+                self.pool.retain_pages(new)
+            self._counts["prefills"] += 1
+            self._counts["prompt_tokens"] += req.prompt_len
+            self._emit(slot, st.entry, self._sample(st.entry, st.rows))
 
     def _advance_prefill(self, budget_tokens: int) -> None:
         """Spend up to ``budget_tokens`` of prefill-chunk work: advance the
@@ -490,7 +526,8 @@ class Engine:
                 self._staging = self._start_prefill(self.queue.pop())
             st = self._staging
             while not st.done and chunks_left > 0:
-                self._prefill_chunk_once(st)
+                with span("engine.prefill_chunk", uid=st.entry.request.uid):
+                    self._prefill_chunk_once(st)
                 chunks_left -= 1
             if not st.done:
                 return                       # budget exhausted mid-prompt
@@ -512,15 +549,18 @@ class Engine:
         return self.pool.can_admit(self.queue.peek())
 
     def _admit_one(self, req: Request) -> None:
-        rows, single_cache = self._prefill_request(req)
-        entry = SlotEntry(request=req, admitted_at=time.perf_counter(),
-                          admit_step=self._step,
-                          admit_index=self._admit_counter,
-                          prefill_offset=req.prompt_len)
-        self._admit_counter += 1
-        self._n_prefills += 1
-        slot = self.pool.admit(entry, single_cache)
-        self._emit(slot, entry, self._sample(entry, rows))
+        with span("engine.admit", uid=req.uid):
+            rows, single_cache = self._prefill_request(req)
+            entry = SlotEntry(request=req, admitted_at=time.perf_counter(),
+                              admit_step=self._step,
+                              admit_index=self._admit_counter,
+                              prefill_offset=req.prompt_len)
+            self._admit_counter += 1
+            self._counts["prefills"] += 1
+            self._counts["prefill_rows"] += req.prompt_len
+            self._counts["prompt_tokens"] += req.prompt_len
+            slot = self.pool.admit(entry, single_cache)
+            self._emit(slot, entry, self._sample(entry, rows))
 
     # ----------------------------------------------------------- the pool
 
@@ -535,16 +575,18 @@ class Engine:
         if self._staging is not None:
             cands.append((self._staging.entry.admit_index, None))
         _, victim = max(cands, key=lambda t: t[0])
-        if victim is None:
-            st = self._staging
-            self._staging = None
-            if st.match is not None:    # release the staging pins
-                self.pool.unpin_pages(st.match.pages)
-            self.queue.requeue(st.entry.request)
-        else:
-            entry = self.pool.evict(victim)
+        entry = (self._staging.entry if victim is None
+                 else self.pool.entries[victim])
+        with span("engine.preempt", uid=entry.request.uid):
+            if victim is None:
+                st = self._staging
+                self._staging = None
+                if st.match is not None:    # release the staging pins
+                    self.pool.unpin_pages(st.match.pages)
+            else:
+                self.pool.evict(victim)
             self.queue.requeue(entry.request)
-        self._n_preemptions += 1
+        self._counts["preemptions"] += 1
 
     def _note_backpressure(self, reason: str, uid: str | None,
                            pages_needed: int | None = None,
@@ -570,44 +612,44 @@ class Engine:
         by rollback. The oldest slot alone always fits: its ensured span
         ends at most at ``prompt + max_new - 1 ≤ max_seq - 1``, the
         ``check_fits`` bound."""
-        for slot in sorted(self.pool.entries,
-                           key=lambda s: self.pool.entries[s].admit_index):
-            while slot in self.pool.entries:
-                entry = self.pool.entries[slot]
-                n_keep = min(width, entry.request.max_new_tokens
-                             - entry.n_generated)
-                base = entry.next_write_pos
-                try:
-                    for i in range(n_keep):
-                        self.pool.ensure_page(slot, base + i)
-                    break
-                except PoolExhausted as e:
-                    self._note_backpressure(e.reason, e.uid,
-                                            e.pages_needed, e.pages_free)
-                    if len(self.pool.entries) <= 1 and self._staging is None:
-                        raise   # run() pre-check makes this unreachable
-                    self._preempt_youngest()
+        with span("engine.grow_pages"):
+            for slot in sorted(self.pool.entries,
+                               key=lambda s: self.pool.entries[s].admit_index):
+                while slot in self.pool.entries:
+                    entry = self.pool.entries[slot]
+                    n_keep = min(width, entry.request.max_new_tokens
+                                 - entry.n_generated)
+                    base = entry.next_write_pos
+                    try:
+                        for i in range(n_keep):
+                            self.pool.ensure_page(slot, base + i)
+                        break
+                    except PoolExhausted as e:
+                        self._note_backpressure(e.reason, e.uid,
+                                                e.pages_needed, e.pages_free)
+                        if (len(self.pool.entries) <= 1
+                                and self._staging is None):
+                            raise   # run() pre-check makes this unreachable
+                        self._preempt_youngest()
 
     def _decode_once(self) -> np.ndarray:
         """One batched decode step over every slot; returns the (C, ...)
         last-token logit rows."""
-        batch = {"tokens": jnp.asarray(self._tok_buf)}
         if self.paged:
             self._grow_pages()
-            logits, self.pool.cache = self._decode(
-                self._params, self.pool.cache,
-                jnp.asarray(self.pool.tables), batch)
-        else:
-            logits, self.pool.cache = self._decode(
-                self._params, self.pool.cache, batch)
+        with span("engine.decode"):
+            batch = {"tokens": jnp.asarray(self._tok_buf)}
+            if self.paged:
+                logits, self.pool.cache = self._decode(
+                    self._params, self.pool.cache,
+                    jnp.asarray(self.pool.tables), batch)
+            else:
+                logits, self.pool.cache = self._decode(
+                    self._params, self.pool.cache, batch)
         self._step += 1
-        rows = np.asarray(jax.device_get(logits))[:, -1]
-        now = time.perf_counter()
-        if self._last_decode_end is not None:
-            self._max_decode_gap = max(self._max_decode_gap,
-                                       now - self._last_decode_end)
-        self._last_decode_end = now
-        return rows
+        self._counts["decode_rows"] += len(self.pool.entries)
+        with span("engine.sync"):
+            return np.asarray(jax.device_get(logits))[:, -1]
 
     def _speculate_once(self) -> None:
         """One draft → verify → rollback round (DESIGN.md §14), emitting
@@ -646,22 +688,22 @@ class Engine:
             return      # the window's growth preempted every slot but one,
                         # then that one finished? unreachable, but be safe
         tables = jnp.asarray(self.pool.tables)
-        t0 = time.perf_counter()
-        draft_toks, self.pool.cache = self._draft(
-            self._params, self.pool.cache, tables,
-            {"tokens": jnp.asarray(self._tok_buf)})
-        draft_host = np.asarray(jax.device_get(draft_toks))      # (C, k)
-        t1 = time.perf_counter()
+        with span("engine.draft"):
+            draft_toks, self.pool.cache = self._draft(
+                self._params, self.pool.cache, tables,
+                {"tokens": jnp.asarray(self._tok_buf)})
+        with span("engine.sync"):
+            draft_host = np.asarray(jax.device_get(draft_toks))  # (C, k)
         window = np.concatenate([self._tok_buf, draft_host], axis=1)
-        exact_toks, self.pool.cache = self._verify(
-            self._params, self.pool.cache, tables,
-            {"tokens": jnp.asarray(window)})
-        exact_host = np.asarray(jax.device_get(exact_toks))      # (C, k+1)
-        t2 = time.perf_counter()
+        with span("engine.verify"):
+            exact_toks, self.pool.cache = self._verify(
+                self._params, self.pool.cache, tables,
+                {"tokens": jnp.asarray(window)})
+        with span("engine.sync"):
+            exact_host = np.asarray(jax.device_get(exact_toks))  # (C, k+1)
         self._step += 1
-        self._n_spec_rounds += 1
-        self._spec_draft_s += t1 - t0
-        self._spec_verify_s += t2 - t1
+        self._counts["spec_rounds"] += 1
+        self._counts["decode_rows"] += len(self.pool.entries)
 
         accept = np.zeros((self.capacity,), np.int32)
         emit_n: dict[int, int] = {}
@@ -673,26 +715,23 @@ class Engine:
             t = min(j + 1, remaining)
             accept[slot] = t
             emit_n[slot] = t
-            self._spec_drafted += k
-            self._spec_draft_accepted += min(j, t)
-            self._spec_emitted += t
+            self._counts["spec_drafted_tokens"] += k
+            self._counts["spec_accepted_tokens"] += min(j, t)
+            self._counts["spec_emitted_tokens"] += t
         # rollback BEFORE the emission loop: eviction (eos/length finish)
         # resets a slot's positions and pages itself, and running it first
         # would leave rollback rewinding a slot the pool already recycled
-        self.pool.cache = self._rollback(self.pool.cache, tables,
-                                         jnp.asarray(accept))
-        for slot in self.pool.active_slots:
-            entry = self.pool.entries[slot]
-            for i in range(emit_n[slot]):
-                self._emit(slot, entry, exact_host[slot, i])
-                if slot not in self.pool.entries:
-                    break       # finished (eos/length): drop the tail —
+        with span("engine.rollback"):
+            self.pool.cache = self._rollback(self.pool.cache, tables,
+                                             jnp.asarray(accept))
+        with span("engine.sample"):
+            for slot in self.pool.active_slots:
+                entry = self.pool.entries[slot]
+                for i in range(emit_n[slot]):
+                    self._emit(slot, entry, exact_host[slot, i])
+                    if slot not in self.pool.entries:
+                        break   # finished (eos/length): drop the tail —
                                 # eviction already rewound its positions
-        now = time.perf_counter()
-        if self._last_decode_end is not None:
-            self._max_decode_gap = max(self._max_decode_gap,
-                                       now - self._last_decode_end)
-        self._last_decode_end = now
 
     # ------------------------------------------------------ the scheduler
 
@@ -703,20 +742,30 @@ class Engine:
         surface. Returns whether work remains."""
         if not self.has_work:
             return False
-        if self.prefill_mode == "chunked":
-            if self.continuous:
-                self._advance_prefill(self.prefill_budget)
-            elif not self.pool.entries:
-                # static gang admission: fill the empty pool back-to-back
-                # (the admission stall is the A/B point of static mode)
-                self._advance_prefill(self.max_seq * self.capacity)
-        else:
-            may_admit = self.continuous or not self.pool.entries
-            while may_admit and self.pool.has_free and self.queue \
-                    and self._may_admit_next():
-                self._admit_one(self.queue.pop())
-                if not self.continuous and not self.pool.has_free:
-                    break
+        self._counts["steps"] += 1
+        with span("engine.step"):
+            self._schedule()
+        return self.has_work
+
+    def _schedule(self) -> None:
+        """The body of :meth:`step`: prompt work, then the batched decode
+        and the emission of its tokens."""
+        with span("engine.prefill"):
+            if self.prefill_mode == "chunked":
+                if self.continuous:
+                    self._advance_prefill(self.prefill_budget)
+                elif not self.pool.entries:
+                    # static gang admission: fill the empty pool
+                    # back-to-back (the admission stall is the A/B point of
+                    # static mode)
+                    self._advance_prefill(self.max_seq * self.capacity)
+            else:
+                may_admit = self.continuous or not self.pool.entries
+                while may_admit and self.pool.has_free and self.queue \
+                        and self._may_admit_next():
+                    self._admit_one(self.queue.pop())
+                    if not self.continuous and not self.pool.has_free:
+                        break
         if not self.pool.entries:
             st = self._staging
             if (st is not None and st.done and st.match is not None
@@ -729,7 +778,7 @@ class Engine:
                 # the request unservable. The skipped span still counts as
                 # saved: it was never recomputed.
                 self.pool.unpin_pages(st.match.pages)
-                self._prefill_tokens_saved += st.match.resume
+                self._counts["prefill_tokens_saved"] += st.match.resume
                 st.match = None
                 if self._can_admit_staged(st):
                     self._admit_staged()
@@ -753,15 +802,15 @@ class Engine:
                     f"even into an empty pool "
                     f"(n_blocks={getattr(self.pool, 'n_blocks', None)})",
                     uid=self.queue.peek().uid)
-            return self.has_work    # mid-prefill, or gang finished at admit
+            return                  # mid-prefill, or gang finished at admit
         if self.speculate_k:
             self._speculate_once()
-        else:
-            rows = self._decode_once()
+            return
+        rows = self._decode_once()
+        with span("engine.sample"):
             for slot in self.pool.active_slots:
                 entry = self.pool.entries[slot]
                 self._emit(slot, entry, self._sample(entry, rows[slot]))
-        return self.has_work
 
     # ------------------------------------------------- streaming surface
 
@@ -824,24 +873,15 @@ class Engine:
         for r in requests:
             self.queue.submit(r)
         t0 = time.perf_counter()
-        steps0, prefills0 = self._step, self._n_prefills
-        chunks0, preempt0 = self._n_prefill_chunks, self._n_preemptions
-        hits0, misses0 = self._n_prefix_hits, self._n_prefix_misses
-        nonfinite0 = self._n_nonfinite_rows
-        saved0 = self._prefill_tokens_saved
-        cow0 = getattr(self.pool, "n_cow", 0)
-        reclaim0 = getattr(self.pool, "n_reclaimed", 0)
-        spec0 = (self._n_spec_rounds, self._spec_drafted,
-                 self._spec_draft_accepted, self._spec_emitted,
-                 self._spec_draft_s, self._spec_verify_s)
+        c0 = self.counters()
         self._backpressure = {"admission": [], "decode": []}
-        self._last_decode_end = None
-        self._max_decode_gap = 0.0
 
         while self.step():
             pass
 
         wall = time.perf_counter() - t0
+        c1 = self.counters()
+        d = {k: c1[k] - c0[k] for k in c1}
         if order:
             out = [self._results.pop(uid) for uid in order]
         else:
@@ -864,11 +904,9 @@ class Engine:
             "prefill_mode": self.prefill_mode,
             "requests": len(out),
             "generated_tokens": generated,
-            "decode_steps": self._step - steps0,
-            "prefills": self._n_prefills - prefills0,
-            "prefill_chunks": self._n_prefill_chunks - chunks0,
-            "preemptions": self._n_preemptions - preempt0,
-            "nonfinite_logit_rows": self._n_nonfinite_rows - nonfinite0,
+            **{k: d[k] for k in ("decode_steps", "prefills",
+                                 "prefill_chunks", "preemptions",
+                                 "nonfinite_logit_rows")},
             "wall_s": wall,
             "tok_per_s": generated / wall if wall > 0 else float("inf"),
             "p50_latency_s": pctl(lats, 0.5),
@@ -877,7 +915,6 @@ class Engine:
             "ttft_p99_s": pctl(ttfts, 0.99),
             "itl_p50_s": pctl(itls, 0.5),
             "itl_p99_s": pctl(itls, 0.99),
-            "max_decode_gap_s": self._max_decode_gap,
             "chunk": self.chunk,
             "buckets": self.buckets,
             "prefill_executables": len(self._prefill_shapes),
@@ -894,10 +931,8 @@ class Engine:
             })
         self.stats["speculative"] = bool(self.speculate_k)
         if self.speculate_k:
-            rounds = self._n_spec_rounds - spec0[0]
-            drafted = self._spec_drafted - spec0[1]
-            accepted = self._spec_draft_accepted - spec0[2]
-            emitted = self._spec_emitted - spec0[3]
+            rounds, drafted = d["spec_rounds"], d["spec_drafted_tokens"]
+            accepted = d["spec_accepted_tokens"]
             self.stats.update({
                 "speculate_k": self.speculate_k,
                 "draft_bits": self.draft_bits,
@@ -905,24 +940,18 @@ class Engine:
                 "spec_drafted_tokens": drafted,
                 "spec_accepted_tokens": accepted,
                 "spec_acceptance_rate": accepted / max(drafted, 1),
-                "spec_tokens_per_round": emitted / max(rounds, 1),
-                "spec_draft_us": (self._spec_draft_s - spec0[4]) * 1e6
-                                 / max(rounds, 1),
-                "spec_verify_us": (self._spec_verify_s - spec0[5]) * 1e6
-                                  / max(rounds, 1),
+                "spec_tokens_per_round":
+                    d["spec_emitted_tokens"] / max(rounds, 1),
             })
         self.stats["prefix_cache"] = self.prefix is not None
         if self.prefix is not None:
-            hits = self._n_prefix_hits - hits0
-            misses = self._n_prefix_misses - misses0
+            hits, misses = d["prefix_hits"], d["prefix_misses"]
             self.stats.update({
                 "prefix_hits": hits,
                 "prefix_misses": misses,
                 "prefix_hit_rate": hits / max(hits + misses, 1),
-                "prefill_tokens_saved":
-                    self._prefill_tokens_saved - saved0,
-                "cow_copies": self.pool.n_cow - cow0,
-                "prefix_reclaims": self.pool.n_reclaimed - reclaim0,
+                **{k: d[k] for k in ("prefill_tokens_saved", "cow_copies",
+                                     "prefix_reclaims")},
                 "prefix_retained_pages": len(self.pool.retained),
             })
         return out

@@ -99,11 +99,18 @@ class RequestResult:
 
 class RequestQueue:
     """FCFS waiting line. ``submit`` appends; ``pop`` hands the engine the
-    oldest waiting request."""
+    oldest waiting request.
+
+    Each request is stamped with the host time it entered the line (on
+    ``submit`` and again on ``requeue``); ``pops`` and ``wait_s`` total the
+    requests popped and the seconds each waited since its stamp."""
 
     def __init__(self, requests: Any = ()):  # iterable of Request
         self._q: deque[Request] = deque()
         self._seen: set[str] = set()
+        self._since: dict[str, float] = {}
+        self.pops = 0
+        self.wait_s = 0.0
         for r in requests:
             self.submit(r)
 
@@ -111,6 +118,7 @@ class RequestQueue:
         if request.uid in self._seen:
             raise ConfigError(f"duplicate request uid {request.uid!r}")
         self._seen.add(request.uid)
+        self._since[request.uid] = time.perf_counter()
         self._q.append(request)
 
     def requeue(self, request: Request) -> None:
@@ -122,10 +130,14 @@ class RequestQueue:
         from offset 0 on re-admission; determinism makes the replayed
         stream bit-identical, so correctness never depends on how far the
         abandoned prefill got."""
+        self._since[request.uid] = time.perf_counter()
         self._q.appendleft(request)
 
     def pop(self) -> Request:
-        return self._q.popleft()
+        request = self._q.popleft()
+        self.pops += 1
+        self.wait_s += time.perf_counter() - self._since.pop(request.uid)
+        return request
 
     def peek(self) -> Request:
         return self._q[0]

@@ -601,17 +601,20 @@ def test_prefill_tokens_saved_counts_at_admission(dense_params):
     prompt = _prompt(cfg, 16, seed=53)
     engine = Engine(cfg, params, capacity=2, max_seq=32, block=4, chunk=4)
     engine.run([Request(uid="warm", prompt=prompt, max_new_tokens=2)])
-    saved0 = engine._prefill_tokens_saved
+    def saved():
+        return engine.counters()["prefill_tokens_saved"]
+
+    saved0 = saved()
     engine.submit(Request(uid="x", prompt=prompt.copy(), max_new_tokens=2))
     engine._staging = engine._start_prefill(engine.queue.pop())
     assert engine._staging.match is not None       # warm tree: a hit
-    assert engine._prefill_tokens_saved == saved0  # nothing yet
+    assert saved() == saved0                       # nothing yet
     engine._preempt_youngest()                     # drop staging, requeue
-    assert engine._prefill_tokens_saved == saved0  # still nothing
+    assert saved() == saved0                       # still nothing
     results = engine.run([])                       # re-stage + admit
     assert [r.uid for r in results] == ["x"]
     # counted exactly once, at admission: resume = 12 for this geometry
-    assert engine._prefill_tokens_saved == saved0 + 12
+    assert saved() == saved0 + 12
     _assert_drained(engine.pool)
 
 
