@@ -38,7 +38,9 @@ just loops over it.
   is re-queued with its partial progress discarded (determinism makes the
   restarted stream bit-identical).
 * *Decode (batched)*: one ``cached_paged_decode_step`` (or
-  ``cached_decode_step``) call advances all live slots a token; sampled
+  ``cached_decode_step``) call advances all live slots a token. The
+  device reduces each row to its greedy token, and the host fetches those
+  ids, never the logits, unless a request samples (temperature > 0). The
   tokens are *streamed* — pushed through per-request ``on_token``
   callbacks the moment they exist, or pulled through the
   :meth:`Engine.stream` generator, which drives ``step()`` on demand.
@@ -101,7 +103,8 @@ span = jax.profiler.TraceAnnotation
 _COUNTS = ("steps", "decode_rows", "prefill_chunks", "prefill_rows",
            "prefills", "prompt_tokens", "prefix_hits", "prefix_misses",
            "prefill_tokens_saved", "preemptions", "nonfinite_logit_rows",
-           "spec_rounds", "spec_drafted_tokens", "spec_accepted_tokens",
+           "device_sampled_rows", "logit_rows_fetched", "spec_rounds",
+           "spec_drafted_tokens", "spec_accepted_tokens",
            "spec_emitted_tokens")
 
 #: ``on_token(uid, index, token, finished_reason)`` — ``index`` is the
@@ -110,6 +113,39 @@ _COUNTS = ("steps", "decode_rows", "prefill_chunks", "prefill_rows",
 #: request *replays* its stream from index 0 (bit-identically); pull-side
 #: consumers (``Engine.stream``) dedupe by index.
 TokenCallback = Callable[[str, int, np.ndarray, "str | None"], None]
+
+
+@jax.jit
+def _reduce_rows(logits, fetch):
+    """``(B, 1, ..., V)`` logits -> each row's argmax over the last axis
+    (int32, the first maximum, or the first NaN where there is one, as
+    ``np.argmax``), whether the row is all finite, and the last-position
+    logits of the rows ``fetch`` names (``(n,)`` int32)."""
+    rows = logits[:, -1]
+    nan = jnp.isnan(rows)
+    # the TPU's argmax need not return the first of several NaNs
+    v = rows.shape[-1]
+    first_nan = jnp.min(jnp.where(nan, jnp.arange(v), v), axis=-1)
+    tok = jnp.where(nan.any(axis=-1), first_nan, jnp.argmax(rows, axis=-1))
+    finite = jnp.isfinite(rows).reshape(rows.shape[0], -1).all(axis=1)
+    return tok.astype(jnp.int32), finite, rows[fetch]
+
+
+class _Row:
+    """One batch row of a step: ``token``, its argmax, and ``finite``,
+    whether its logits are all finite, both computed on the device.
+    ``np.asarray`` gives the logits themselves, ``logits(i)``: the step's
+    fetch shares them among its rows."""
+
+    def __init__(self, token: np.ndarray, finite: bool,
+                 logits: Callable[[int], np.ndarray], i: int):
+        self.token = token
+        self.finite = finite
+        self._logits = logits
+        self._i = i
+
+    def __array__(self, dtype=None, copy=None):
+        return np.array(self._logits(self._i), dtype=dtype, copy=copy)
 
 
 def default_serving_mesh() -> Mesh:
@@ -132,7 +168,7 @@ class _StagingPrefill:
     bucket: int
     step: Any                    # the cached (bucket, chunk) jitted step
     cache: Any                   # B=1 staging cache, threaded through chunks
-    rows: np.ndarray | None = None
+    rows: _Row | None = None
     match: PrefixMatch | None = None
 
     @property
@@ -309,6 +345,11 @@ class Engine:
         ``prefix_misses`` and ``prefill_tokens_saved`` (prompt tokens a
         prefix hit seeded instead of computing); ``preemptions``,
         ``cow_copies``, ``prefix_reclaims`` and ``nonfinite_logit_rows``;
+        ``device_sampled_rows`` (tokens taken from the device's greedy
+        pick: a greedy request's every decode row and admission) and
+        ``logit_rows_fetched`` (logit rows copied to the host: a step's
+        ``temperature > 0`` rows, padded to a power of two, and all of a
+        step's rows where a caller reads a greedy row's logits);
         ``queue_pops`` and ``queue_wait_s`` (seconds from ``submit`` or a
         preemption's requeue to the pop that starts the request's prefill,
         summed); and the speculative ``spec_rounds``,
@@ -346,30 +387,59 @@ class Engine:
         self._prefill_shapes.add((req.prompt_len, 0))
         batch = {"tokens": jnp.asarray(req.prompt)[None]}
         logits, cache = prefill(self._params, batch)
-        with span("engine.sync"):
-            return np.asarray(jax.device_get(logits))[0, -1], cache
+        rows = self._rows(logits, [0] if req.temperature > 0 else [])
+        return rows[0], cache
 
-    def _sample(self, entry: SlotEntry, row: np.ndarray) -> np.ndarray:
+    def _rows(self, logits, sampled: Sequence[int]) -> list[_Row]:
+        """The rows of a step's ``(B, 1, ..., V)`` logits, for
+        :meth:`_sample`, from one ``device_get``: every row's greedy token
+        and finite flag, reduced on the device, and the logits of the rows
+        ``sampled`` names (those of ``temperature > 0`` requests), padded to
+        a power of two so that the fetch compiles for few shapes. Any other
+        row's logits are fetched, with the whole step's, only if asked."""
+        b, n = logits.shape[0], len(sampled)
+        pad = min(1 << (n - 1).bit_length(), b) if n else 0
+        idx = np.asarray(list(sampled) + list(sampled[-1:]) * (pad - n),
+                         np.int32)
+        out = _reduce_rows(logits, idx)
+        with span("engine.sync"):
+            tok, finite, got = jax.device_get(out)
+        self._counts["logit_rows_fetched"] += pad
+        host = dict(zip(sampled, got))
+
+        def row(i: int) -> np.ndarray:
+            if i not in host:
+                self._counts["logit_rows_fetched"] += b
+                with span("engine.sync"):
+                    host.update(enumerate(
+                        np.asarray(jax.device_get(logits[:, -1]))))
+            return host[i]
+        return [_Row(tok[i], bool(finite[i]), row, i) for i in range(b)]
+
+    def _sample(self, entry: SlotEntry, row: _Row) -> np.ndarray:
         """One token from a logit row ((V,) or (K, V) for codebooks).
 
-        Greedy is pure argmax. temperature > 0 walks a per-request PRNG
-        chain (seeded by the request, split once per emitted token), so a
-        stream is a function of the request alone — which slot or engine
-        step produced it is irrelevant (and a preempted, restarted request
-        regenerates the identical stream). A row holding NaN or inf is
-        counted (``stats["nonfinite_logit_rows"]``): argmax over it still
-        returns an id, so without the count the fault would be silent.
+        Greedy takes the row's argmax, picked on the device. temperature > 0
+        takes the row's logits, fetched with the step's tokens, and walks a
+        per-request PRNG chain (seeded by the request, split once per
+        emitted token), so a stream is a function of the request alone —
+        which slot or engine step produced it is irrelevant (and a
+        preempted, restarted request regenerates the identical stream). A
+        row holding NaN or inf is counted (``stats["nonfinite_logit_rows"]``):
+        argmax over it still returns an id, so without the count the fault
+        would be silent.
         """
-        if not np.isfinite(row).all():
+        if not row.finite:
             self._counts["nonfinite_logit_rows"] += 1
         req = entry.request
         if req.temperature <= 0:
-            return np.argmax(row, axis=-1).astype(np.int32)
+            self._counts["device_sampled_rows"] += 1
+            return row.token
         if entry.key is None:
             entry.key = jax.random.PRNGKey(req.seed)
         entry.key, sub = jax.random.split(entry.key)
         tok = jax.random.categorical(
-            sub, jnp.asarray(row) / req.temperature, axis=-1)
+            sub, jnp.asarray(np.asarray(row)) / req.temperature, axis=-1)
         return np.asarray(tok, np.int32)
 
     def _finish_reason(self, entry: SlotEntry, tok: np.ndarray) -> str | None:
@@ -467,8 +537,8 @@ class Engine:
         self._counts["prefill_chunks"] += 1
         self._counts["prefill_rows"] += nv
         if st.done:
-            with span("engine.sync"):
-                st.rows = np.asarray(jax.device_get(logits))[0, -1]
+            st.rows = self._rows(logits,
+                                 [0] if req.temperature > 0 else [])[0]
 
     def _can_admit_staged(self, st: _StagingPrefill) -> bool:
         if not self.pool.has_free:
@@ -632,9 +702,8 @@ class Engine:
                             raise   # run() pre-check makes this unreachable
                         self._preempt_youngest()
 
-    def _decode_once(self) -> np.ndarray:
-        """One batched decode step over every slot; returns the (C, ...)
-        last-token logit rows."""
+    def _decode_once(self) -> list[_Row]:
+        """One batched decode step over every slot; returns its C rows."""
         if self.paged:
             self._grow_pages()
         with span("engine.decode"):
@@ -648,8 +717,9 @@ class Engine:
                     self._params, self.pool.cache, batch)
         self._step += 1
         self._counts["decode_rows"] += len(self.pool.entries)
-        with span("engine.sync"):
-            return np.asarray(jax.device_get(logits))[:, -1]
+        return self._rows(logits, [
+            slot for slot, e in sorted(self.pool.entries.items())
+            if e.request.temperature > 0])
 
     def _speculate_once(self) -> None:
         """One draft → verify → rollback round (DESIGN.md §14), emitting

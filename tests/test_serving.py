@@ -407,3 +407,158 @@ def test_chunked_prefill_bit_identical_fuzz_deep(data):
     """The long sweep (scheduled CI / `pytest -m slow`): more schedules,
     chunk sizes, and partial-final-chunk prompt lengths per family."""
     _assert_chunked_matches_oneshot_and_sequential(data, CASES)
+
+
+# ------------------------------------------------ the device's greedy pick
+
+class _HostSampling(Engine):
+    """The engine sampling every row on the host: the row's logits fetched,
+    checked for finite values and reduced by numpy (the reference the
+    device's greedy pick must match token for token)."""
+
+    def _sample(self, entry, row):
+        row = np.asarray(row)
+        if not np.isfinite(row).all():
+            self._counts["nonfinite_logit_rows"] += 1
+        req = entry.request
+        if req.temperature <= 0:
+            return np.argmax(row, axis=-1).astype(np.int32)
+        if entry.key is None:
+            entry.key = jax.random.PRNGKey(req.seed)
+        entry.key, sub = jax.random.split(entry.key)
+        tok = jax.random.categorical(
+            sub, jnp.asarray(row) / req.temperature, axis=-1)
+        return np.asarray(tok, np.int32)
+
+
+#: (name, Engine keywords) of the paths that sample a decode or prompt row.
+PATHS = [("chunked", {}), ("oneshot", {"prefill_mode": "oneshot"}),
+         ("contiguous", {"paged": False}),
+         ("static", {"continuous": False})]
+
+
+def _doctor(logits):
+    """Exact ties in every row (logits on a grid of quarters), two NaNs in
+    row 0, two +inf in row 1 and a row of -inf in row 2."""
+    x = jnp.round(logits * 4) / 4
+    x = x.at[0, ..., 9].set(jnp.nan).at[0, ..., 3].set(jnp.nan)
+    x = x.at[1, ..., 7].set(jnp.inf).at[1, ..., 2].set(jnp.inf)
+    return x.at[2].set(-jnp.inf)
+
+
+def _doctoring(real):
+    def build(*a, **k):
+        fn, shardings, abstract = real(*a, **k)
+
+        def decode(*args):
+            logits, cache = fn(*args)
+            return _doctor(logits), cache
+        return decode, shardings, abstract
+    return build
+
+
+def _mixed_requests(cfg, temperatures, seed=21):
+    prompts = _prompts(cfg, len(temperatures), s=6, seed=seed)
+    return [Request(uid=f"r{i}", prompt=p, max_new_tokens=3 + 2 * (i % 3),
+                    temperature=t, seed=100 + i)
+            for i, (p, t) in enumerate(zip(prompts, temperatures))]
+
+
+def _run_both(cfg, params, kw, temperatures):
+    out = []
+    for cls in (Engine, _HostSampling):
+        engine = cls(cfg, params, capacity=4, max_seq=16, block=4, chunk=4,
+                     **kw)
+        c0 = engine.counters()
+        results = engine.run(_mixed_requests(cfg, temperatures))
+        c1 = engine.counters()
+        out.append((results, {k: c1[k] - c0[k] for k in c1}))
+    return out
+
+
+@pytest.mark.parametrize("kw", [kw for _, kw in PATHS],
+                         ids=[n for n, _ in PATHS])
+def test_device_pick_matches_host_argmax_on_ties_and_nonfinite_rows(
+        kw, monkeypatch):
+    """A decode step whose rows hold exact ties, NaN, +inf and -inf: the
+    device's argmax takes the first maximum (the first NaN where there is
+    one), as ``np.argmax`` does, and ``nonfinite_logit_rows`` counts the
+    same rows as the host's ``np.isfinite``."""
+    from repro.serving import engine as engine_mod
+    for name in ("cached_paged_decode_step", "cached_decode_step"):
+        monkeypatch.setattr(engine_mod, name,
+                            _doctoring(getattr(engine_mod, name)))
+    cfg = CASES[0]
+    (got, c_dev), (want, c_host) = _run_both(cfg, _params(cfg), kw,
+                                             [0.0] * 6)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.tokens, w.tokens, err_msg=g.uid)
+    assert c_dev["nonfinite_logit_rows"] == c_host["nonfinite_logit_rows"] > 0
+    assert c_dev["logit_rows_fetched"] == 0
+
+
+@pytest.mark.parametrize("kw", [kw for _, kw in PATHS],
+                         ids=[n for n, _ in PATHS])
+def test_mixed_greedy_and_sampled_batch_streams_unchanged(kw):
+    """Greedy and ``temperature > 0`` requests share decode steps: every
+    stream is the host sampler's, and only the sampled requests' rows cross
+    to the host, padded to a power of two (here at most 3 rows to 4)."""
+    cfg = CASES[0]
+    temperatures = [0.0, 0.8, 0.0, 1.5, 0.0, 0.8]
+    (got, c_dev), (want, _) = _run_both(cfg, _params(cfg), kw, temperatures)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.tokens, w.tokens, err_msg=g.uid)
+    sampled = sum(g.n_generated for g, t in zip(got, temperatures) if t > 0)
+    greedy = sum(g.n_generated for g, t in zip(got, temperatures) if t <= 0)
+    assert 0 < sampled <= c_dev["logit_rows_fetched"] <= 4 * sampled // 3
+    assert c_dev["device_sampled_rows"] == greedy
+
+
+@pytest.mark.parametrize("kw", [kw for _, kw in PATHS],
+                         ids=[n for n, _ in PATHS])
+def test_sampled_batch_makes_one_fetch_per_step(kw, monkeypatch):
+    """A batch in which every request samples: each decode step and each
+    admission makes one ``device_get``, which brings the sampled rows'
+    logits with the tokens (at most 3 rows padded to 4), and no second
+    fetch for any row."""
+    from repro.serving import engine as engine_mod
+    fetches = [0]
+    real = jax.device_get
+
+    def counting(x):
+        fetches[0] += 1
+        return real(x)
+    monkeypatch.setattr(engine_mod.jax, "device_get", counting)
+    cfg = CASES[0]
+    engine = Engine(cfg, _params(cfg), capacity=4, max_seq=16, block=4,
+                    chunk=4, **kw)
+    c0 = engine.counters()
+    results = engine.run(_mixed_requests(cfg, [0.8, 1.5, 0.8, 1.0, 0.8]))
+    d = {k: v - c0[k] for k, v in engine.counters().items()}
+    assert fetches[0] == d["decode_steps"] + d["prefills"]
+    assert d["device_sampled_rows"] == 0
+    sampled = sum(r.n_generated for r in results)
+    assert 0 < sampled <= d["logit_rows_fetched"] <= 4 * sampled // 3
+
+
+@pytest.mark.parametrize(
+    "kw", [kw for _, kw in PATHS] + [{"n_blocks": 8},
+                                     {"prefill_mode": "oneshot",
+                                      "n_blocks": 8}],
+    ids=[n for n, _ in PATHS] + ["preempting", "oneshot-preempting"])
+def test_all_greedy_run_fetches_no_logit_row(kw):
+    """An all-greedy run copies no logit row to the host: every decode row
+    and every admission (a preempted request's again) takes the device's
+    pick."""
+    cfg = CASES[0]
+    engine = Engine(cfg, _params(cfg), capacity=2, max_seq=12, block=2,
+                    chunk=4, **kw)
+    c0 = engine.counters()
+    results = engine.run([Request(uid=f"r{i}", prompt=p, max_new_tokens=g)
+                          for i, (p, g) in enumerate(zip(
+                              _prompts(cfg, 3, s=4, seed=2), (8, 6, 5)))])
+    d = {k: v - c0[k] for k, v in engine.counters().items()}
+    assert d["logit_rows_fetched"] == 0
+    assert d["device_sampled_rows"] == d["decode_rows"] + d["prefills"]
+    assert d["device_sampled_rows"] >= sum(r.n_generated for r in results)
+    assert (d["preemptions"] > 0) == ("n_blocks" in kw)
