@@ -29,6 +29,21 @@ class ModelConfig:
     # gemma2: (4096, None); llama4: (8192, 8192, 8192, None).
     windows: tuple[int | None, ...] = (None,)
     mrope_sections: tuple[int, ...] | None = None   # qwen2-vl M-RoPE
+    # multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 turns it
+    # on. Keys and values are decompressed per head from a shared latent
+    # c_kv of this width (RMS-normed), plus one rope key of qk_rope_head_dim
+    # shared by all heads; head_dim is qk_nope_head_dim + qk_rope_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (DeepSeek's form): factor 0 turns it off
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- MLP / MoE
     act: str = "silu"                # silu | gelu
@@ -39,8 +54,12 @@ class ModelConfig:
     # which positions in the repeating group are MoE (llama4 alternates);
     # length must divide evenly with len(windows) into the group size.
     moe_flags: tuple[bool, ...] = (False,)
-    router_group_size: int = 512
-    capacity_factor: float = 2.0
+    # leading layers with a dense MLP of d_ff, outside the repeating scan
+    # group (DeepSeek's first_k_dense_replace); the group covers the rest
+    first_k_dense: int = 0
+    # renormalise the top-k gates to sum to one (qwen3); DeepSeek-V2 keeps
+    # the softmax probabilities as they are
+    norm_topk_prob: bool = True
 
     # --- SSM (mamba2 / zamba2)
     ssm_state: int = 0
@@ -108,6 +127,16 @@ class ModelConfig:
         return g
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def scan_layers(self) -> int:
+        """Layers inside the repeating scan group (after the leading dense
+        ones)."""
+        return self.n_layers - self.first_k_dense
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -144,8 +173,18 @@ class ModelConfig:
                 f"got {self.draft_bits}")
         if self.family != "ssm":
             assert self.n_heads % max(self.n_kv_heads, 1) == 0, self.name
-        assert self.n_layers % self.group_size == 0, (
-            f"{self.name}: n_layers {self.n_layers} % group {self.group_size}")
+        assert self.scan_layers % self.group_size == 0, (
+            f"{self.name}: scanned layers {self.scan_layers} % group "
+            f"{self.group_size}")
+        if self.mla:
+            assert self.family in ("dense", "moe"), self.name
+            assert (self.head_dim == self.qk_nope_head_dim
+                    + self.qk_rope_head_dim), (
+                f"{self.name}: MLA head_dim must be nope + rope")
+            assert self.qk_rope_head_dim % 2 == 0 and self.v_head_dim > 0
+            assert self.mrope_sections is None and not self.qkv_bias
+            assert not self.qk_norm and not self.attn_sc, (
+                f"{self.name}: MLA takes no qk_norm and no SC attention")
         if self.shared_attn_every:
             assert self.family == "hybrid"
         return self
@@ -153,7 +192,8 @@ class ModelConfig:
     def reduced(self, **overrides) -> "ModelConfig":
         """Smoke-test sized variant of the same family (tiny but structure-true)."""
         small = dict(
-            n_layers=max(self.group_size * 2, 2 * self.shared_attn_every or 2),
+            n_layers=self.first_k_dense + max(self.group_size * 2,
+                                              2 * self.shared_attn_every or 2),
             d_model=64,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads, 2)),
@@ -164,7 +204,6 @@ class ModelConfig:
             n_experts=min(self.n_experts, 8) if self.n_experts else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             shared_expert_d_ff=32 if self.shared_expert_d_ff else 0,
-            router_group_size=32,
             ssm_state=16 if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state else 64,
             ssm_chunk=16 if self.ssm_state else 128,
@@ -175,6 +214,10 @@ class ModelConfig:
             mrope_sections=(2, 3, 3) if self.mrope_sections else None,
             name=self.name + "-smoke",
         )
+        if self.mla:
+            small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                         qk_rope_head_dim=16, v_head_dim=16, head_dim=32,
+                         n_kv_heads=4)
         small.update(overrides)
         cfg = dataclasses.replace(self, **small)
         return cfg.validate()

@@ -12,5 +12,4 @@ CONFIG = ModelConfig(
     n_experts=128, top_k=1, moe_d_ff=8192, shared_expert_d_ff=8192,
     moe_flags=(False, True), windows=(8192, 8192, 8192, None),
     rope_theta=500000.0,
-    capacity_factor=4.0, router_group_size=512,
 ).validate()

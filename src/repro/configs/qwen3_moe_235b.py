@@ -8,5 +8,4 @@ CONFIG = ModelConfig(
     d_ff=0, vocab_size=151936,
     n_experts=128, top_k=8, moe_d_ff=1536, moe_flags=(True,),
     qk_norm=True, rope_theta=1e6,
-    capacity_factor=2.0, router_group_size=512,
 ).validate()

@@ -2,9 +2,9 @@
 from __future__ import annotations
 
 from .base import ModelConfig
-from . import (gemma2_9b, llama4_maverick_400b, mamba2_130m, musicgen_large,
-               qwen2_5_14b, qwen2_7b, qwen2_vl_2b, qwen3_moe_235b,
-               smollm_360m, zamba2_7b)
+from . import (deepseek_v2_lite, gemma2_9b, llama4_maverick_400b,
+               mamba2_130m, musicgen_large, qwen2_5_14b, qwen2_7b,
+               qwen2_vl_2b, qwen3_moe_235b, smollm_360m, zamba2_7b)
 
 ARCHS: dict[str, ModelConfig] = {
     "qwen2-7b": qwen2_7b.CONFIG,
@@ -17,6 +17,7 @@ ARCHS: dict[str, ModelConfig] = {
     "zamba2-7b": zamba2_7b.CONFIG,
     "qwen2-vl-2b": qwen2_vl_2b.CONFIG,
     "mamba2-130m": mamba2_130m.CONFIG,
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
 }
 
 

@@ -88,7 +88,12 @@ def sc_gemm_problems(cfg: ModelConfig, shape: Shape) -> list[tuple[int, int, int
     tokens = shape.global_batch * (1 if shape.kind == "decode" else shape.seq_len)
     d = cfg.d_model
     probs: set[tuple[int, int, int]] = set()
-    if cfg.family != "ssm":
+    if cfg.mla:
+        h, r = cfg.n_heads, cfg.kv_lora_rank
+        probs.add((tokens, d, h * cfg.head_dim))                # wq
+        probs.add((tokens, d, r + cfg.qk_rope_head_dim))        # wkv_a
+        probs.add((tokens, h * cfg.v_head_dim, d))              # wo
+    elif cfg.family != "ssm":
         h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         probs.add((tokens, d, h * hd))          # wq
         probs.add((tokens, d, kv * hd))         # wk, wv
@@ -97,9 +102,7 @@ def sc_gemm_problems(cfg: ModelConfig, shape: Shape) -> list[tuple[int, int, int
         probs.add((tokens, d, cfg.d_ff))        # w1, w3
         probs.add((tokens, cfg.d_ff, d))        # w2
     if cfg.n_experts and cfg.moe_d_ff:
-        from repro.models.moe import moe_capacity
-        g = min(cfg.router_group_size, tokens)
-        rows = (tokens // g) * moe_capacity(cfg)  # per-expert dispatch rows
+        rows = tokens * cfg.top_k   # the dropless layout: every row, each expert
         probs.add((rows, d, cfg.moe_d_ff))
         probs.add((rows, cfg.moe_d_ff, d))
         if cfg.shared_expert_d_ff:

@@ -41,9 +41,19 @@ view the family decode steps already consume, and ``paged_commit`` scatters
 the one token each decode step appends back into its page — so the decode
 numerics are untouched and streams stay bit-identical to the contiguous
 layout (the invariant tests/test_paging.py fuzzes).
+
+The paged pool's edits (admission, eviction, page zeroing, copy-on-write,
+prefix seeding) are jitted, with page lists padded by the trash block and
+slots, lengths and offsets as runtime values, so a serving engine compiles
+a few of each rather than one for every prompt length and count of pages;
+each donates the pool it is given and updates it in place (the caller keeps
+only what it returns). A ``counts`` field
+(MoE load counters, ``transformer.KVCache``) is pool-wide: every edit
+passes it through, and an admission adds the admitted cache's into it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -65,6 +75,11 @@ SLOT_AXIS = 1
 #: Name of the per-sequence position field in every family's cache.
 _POS_FIELD = "pos"
 
+#: Name of the pool-wide accumulator field (``transformer.KVCache.counts``):
+#: not per slot, so the slot and page edits below pass it through, and an
+#: admission adds the admitted single-sequence cache's counts into it.
+_POOL_FIELD = "counts"
+
 #: Field names whose leaves carry a per-token sequence axis (axis 2) and are
 #: therefore paged; every other leaf is O(1) per sequence and stays
 #: slot-indexed. All three family caches route attention KV through fields
@@ -82,6 +97,45 @@ def _is_pos(path: tuple) -> bool:
 
 def _is_seq(path: tuple) -> bool:
     return any(_entry_name(p) in SEQ_FIELDS for p in path)
+
+
+def _is_pool(path: tuple) -> bool:
+    return _entry_name(path[-1]) == _POOL_FIELD
+
+
+def _by_kind(*trees, pos, seq, slot, pool=None):
+    """``tree_map`` over ``trees`` (structured like the first) calling, for
+    each leaf, the function of its kind: ``pos``, a sequence leaf
+    (``seq``), a pool-wide counter (``pool``; default: the first tree's
+    leaf) or any other slot leaf (``slot``)."""
+    def one(path, *leaves):
+        if _is_pos(path):
+            return pos(*leaves)
+        if _is_pool(path):
+            return leaves[0] if pool is None else pool(*leaves)
+        if _is_seq(path):
+            return seq(*leaves)
+        return slot(*leaves)
+    return jax.tree_util.tree_map_with_path(one, *trees)
+
+
+def _pad_pages(pages, fill: int) -> jax.Array:
+    """``pages`` as int32, padded with ``fill`` (the trash block) to the next
+    power of two: a jitted page edit then compiles for a few lengths, not
+    for every count of pages."""
+    pages = np.asarray(pages, np.int32).reshape(-1)
+    n = 1 << max(pages.size - 1, 0).bit_length()
+    out = np.full((n,), fill, np.int32)
+    out[:pages.size] = pages
+    return jnp.asarray(out)
+
+
+def _trash_of(data: Any) -> int:
+    """The trash block's index in a paged pool: the last page."""
+    for path, leaf in jax.tree_util.tree_flatten_with_path(data)[0]:
+        if _is_seq(path) and not _is_pos(path):
+            return _trash(leaf)
+    return 0
 
 
 def _check_rank(leaf) -> None:
@@ -103,6 +157,8 @@ def slot_insert(pool: Any, single: Any, slot) -> Any:
     def one(path, pl, sl):
         if _is_pos(path):
             return pl.at[slot].set(jnp.reshape(sl, (-1,))[0])
+        if _is_pool(path):
+            return pl + sl
         _check_rank(pl)
         start = (jnp.zeros((), jnp.int32), slot) + \
             (jnp.zeros((), jnp.int32),) * (pl.ndim - 2)
@@ -119,6 +175,8 @@ def slot_read(pool: Any, slot) -> Any:
     def one(path, pl):
         if _is_pos(path):
             return jax.lax.dynamic_slice_in_dim(pl, slot, 1)
+        if _is_pool(path):
+            return pl
         _check_rank(pl)
         return jax.lax.dynamic_slice_in_dim(pl, slot, 1, axis=SLOT_AXIS)
 
@@ -139,6 +197,8 @@ def slot_evict(pool: Any, slot) -> Any:
     def one(path, pl):
         if _is_pos(path):
             return pl.at[slot].set(0)
+        if _is_pool(path):
+            return pl
         _check_rank(pl)
         return pl.at[:, slot].set(jnp.zeros_like(pl[:, slot]))
 
@@ -358,13 +418,16 @@ def paged_rollback(data: Any, tables: jax.Array, *, block: int, width: int,
 
 def paged_insert(data: Any, single: Any, slot: int,
                  pages: np.ndarray | list[int], *, block: int,
-                 start: int = 0) -> Any:
+                 start: int = 0, length: int | None = None) -> Any:
     """Write a single-sequence (B=1) prefill cache into ``pages`` of the
     paged pool and ``slot`` of the slot leaves.
 
-    With ``start == 0`` (the default), ``pages`` must hold
-    ``ceil(S1 / block)`` physical page ids (host ints — page allocation is
-    host-driven); the last page's tail beyond ``S1`` is zero-padded.
+    ``length`` (default: ``single``'s sequence extent) is the prompt's
+    length: rows at or past it are written as zeros, so a bucket-padded
+    staging cache goes in as :func:`truncate_seq` would have cut it. With
+    ``start == 0`` (the default), ``pages`` must hold ``ceil(length /
+    block)`` physical page ids (host ints — page allocation is
+    host-driven); the last page's tail beyond ``length`` is zero-padded.
 
     ``start > 0`` is the prefix-cache admission path (DESIGN.md §12):
     ``pages`` then covers only the token span from ``start``'s page onward
@@ -373,42 +436,69 @@ def paged_insert(data: Any, single: Any, slot: int,
     copy-on-write admission exact: the page copy supplies the shared rows
     the staging prefill never computed, and ``single`` supplies everything
     from the divergence point. Slot leaves and ``pos`` are always taken
-    wholesale from ``single``. Returns the new pool pytree.
+    wholesale from ``single``; pool counters add ``single``'s.
+
+    One jitted program per extent of ``single`` (``length``, ``start`` and
+    ``slot`` are runtime values; logical pages with nothing to write go to
+    the trash block), so a serving engine that admits its bucket-padded
+    staging caches compiles one per prompt bucket, and one more where a
+    resume point falls inside a page. ``data`` is donated.
+    Returns the new pool pytree.
     """
-    pages = jnp.asarray(np.asarray(pages, np.int32))
-    n_pages = int(pages.shape[0])
-    pstart = (start // block) * block
+    seq = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+        single)[0] if _is_seq(path) and not _is_pos(path)]
+    s1 = int(seq[0].shape[2]) if seq else 0
+    length = s1 if length is None else int(length)
+    pages = np.asarray(pages, np.int32).reshape(-1)
+    first = start // block
+    if (first + pages.size) * block < length:
+        raise CacheLayoutError(
+            f"{pages.size} pages of {block} tokens at token offset "
+            f"{first * block} cannot hold a {length}-token prefill cache")
+    n = -(-s1 // block)
+    ids = np.full((n,), _trash_of(data), np.int32)
+    real = pages[:max(n - first, 0)]
+    ids[first:first + real.size] = real
+    return _insert(data, single, jnp.int32(slot), jnp.asarray(ids),
+                   jnp.int32(length), jnp.int32(start), block=block,
+                   overlay=start > first * block)
 
-    def one(path, pl, sl):
-        if _is_pos(path):
-            return pl.at[slot].set(jnp.reshape(sl, (-1,))[0])
-        if not _is_seq(path):
-            _check_rank(pl)
-            start_ix = (jnp.zeros((), jnp.int32), jnp.asarray(slot, jnp.int32)) \
-                + (jnp.zeros((), jnp.int32),) * (pl.ndim - 2)
-            return jax.lax.dynamic_update_slice(pl, sl.astype(pl.dtype),
-                                                start_ix)
-        lead, s1 = sl.shape[0], sl.shape[2]
-        if pstart + n_pages * block < s1:
-            raise CacheLayoutError(
-                f"{n_pages} pages of {block} tokens at token offset "
-                f"{pstart} cannot hold a {s1}-token prefill cache")
-        x = sl[:, 0, pstart:]                             # (lead, S1', *tail)
-        pad = n_pages * block - x.shape[1]
-        if pad:
-            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        x = x.reshape(lead, n_pages, block, *x.shape[2:])
-        if start > pstart:
-            # overlay: cells below ``start`` keep the pool's current
-            # contents (the CoW copy); cells at/after it take ``single``'s
-            cell = pstart + jnp.arange(n_pages * block).reshape(n_pages,
-                                                                block)
-            keep = (cell < start).reshape((1,) + cell.shape
-                                          + (1,) * (x.ndim - 3))
-            x = jnp.where(keep, pl[:, pages], x.astype(pl.dtype))
-        return pl.at[:, pages].set(x.astype(pl.dtype))
 
-    return jax.tree_util.tree_map_with_path(one, data, single)
+def _insert_impl(data, single, slot, ids, length, start, *, block, overlay):
+    n = ids.shape[0]
+    cell = jnp.arange(n * block).reshape(n, block)
+
+    def seq(pl, sl):
+        lead = sl.shape[0]
+        x = sl[:, 0]                                      # (lead, S1, *tail)
+        pad = n * block - x.shape[1]
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape(lead, n, block, *x.shape[2:]).astype(pl.dtype)
+        shape = (1,) + cell.shape + (1,) * (x.ndim - 3)
+        x = jnp.where((cell < length).reshape(shape), x, 0)
+        if overlay:
+            # cells below ``start`` keep the pool's current contents (the
+            # CoW copy); at and after it they take ``single``'s. Only an
+            # admission whose resume point falls inside a page reads the
+            # pool: on a TPU that gather copies the whole pool to another
+            # layout, as the scatter below does once more.
+            x = jnp.where((cell < start).reshape(shape), pl[:, ids], x)
+        return pl.at[:, ids].set(x)
+
+    def slot_leaf(pl, sl):
+        _check_rank(pl)
+        start_ix = (jnp.zeros((), jnp.int32), slot) \
+            + (jnp.zeros((), jnp.int32),) * (pl.ndim - 2)
+        return jax.lax.dynamic_update_slice(pl, sl.astype(pl.dtype), start_ix)
+
+    return _by_kind(
+        data, single, seq=seq, slot=slot_leaf,
+        pos=lambda pl, sl: pl.at[slot].set(jnp.reshape(sl, (-1,))[0]),
+        pool=lambda pl, sl: pl + sl)
+
+
+_insert = jax.jit(_insert_impl, static_argnames=("block", "overlay"),
+                  donate_argnums=(0,))
 
 
 def paged_evict(data: Any, slot: int, pages: np.ndarray | list[int]) -> Any:
@@ -417,22 +507,25 @@ def paged_evict(data: Any, slot: int, pages: np.ndarray | list[int]) -> Any:
     Zeroing freed pages keeps pool contents a pure function of the live
     requests (same argument as :func:`slot_evict`) — a reused page never
     leaks a previous tenant's KV into debugging dumps, even though the
-    position mask already keeps it out of the math.
+    position mask already keeps it out of the math. One program per length
+    of ``pages``: a pool pads its lists with the trash block to its table
+    width, so it compiles one eviction. ``data`` is donated.
     """
-    pages = np.asarray(pages, np.int32)
+    return _evict(data, jnp.int32(slot),
+                  jnp.asarray(np.asarray(pages, np.int32).reshape(-1)))
 
-    def one(path, pl):
-        if _is_pos(path):
-            return pl.at[slot].set(0)
-        if not _is_seq(path):
-            _check_rank(pl)
-            return pl.at[:, slot].set(jnp.zeros_like(pl[:, slot]))
-        if pages.size == 0:
-            return pl
-        ids = jnp.asarray(pages)
-        return pl.at[:, ids].set(jnp.zeros_like(pl[:, ids]))
 
-    return jax.tree_util.tree_map_with_path(one, data)
+def _evict_impl(data, slot, ids):
+    def slot_leaf(pl):
+        _check_rank(pl)
+        return pl.at[:, slot].set(jnp.zeros_like(pl[:, slot]))
+
+    return _by_kind(
+        data, slot=slot_leaf, pos=lambda pl: pl.at[slot].set(0),
+        seq=lambda pl: pl.at[:, ids].set(jnp.zeros_like(pl[:, ids])))
+
+
+_evict = jax.jit(_evict_impl, donate_argnums=(0,))
 
 
 def paged_read(data: Any, tables: jax.Array, slot: int, *,
@@ -455,14 +548,18 @@ def paged_copy_page(data: Any, src: int, dst: int) -> Any:
     (refcount > 1 or prefix-retained) page, the pool copies it to a private
     page and rewrites the slot's block table. Only sequence leaves have
     page axes; slot leaves and ``pos`` pass through. ``src``/``dst`` are
-    host ints — CoW decisions are host-driven like all page allocation.
+    host ints — CoW decisions are host-driven like all page allocation —
+    passed to one jitted program as runtime values. ``data`` is donated.
     """
-    def one(path, pl):
-        if _is_pos(path) or not _is_seq(path):
-            return pl
-        return pl.at[:, dst].set(pl[:, src])
+    return _copy(data, jnp.int32(src), jnp.int32(dst))
 
-    return jax.tree_util.tree_map_with_path(one, data)
+
+def _copy_impl(data, src, dst):
+    return _by_kind(data, pos=lambda pl: pl, slot=lambda pl: pl,
+                    seq=lambda pl: pl.at[:, dst].set(pl[:, src]))
+
+
+_copy = jax.jit(_copy_impl, donate_argnums=(0,))
 
 
 def paged_zero_pages(data: Any, pages: np.ndarray | list[int]) -> Any:
@@ -472,17 +569,22 @@ def paged_zero_pages(data: Any, pages: np.ndarray | list[int]) -> Any:
     reuse after its last reference dropped is zeroed only when the LRU
     reclaimer finally hands it back to the free list, preserving the
     pool-contents-are-a-pure-function-of-live-state argument of
-    :func:`paged_evict`.
+    :func:`paged_evict`. One program per length of ``pages``, as there;
+    ``data`` is donated.
     """
-    pages = np.asarray(pages, np.int32)
+    pages = np.asarray(pages, np.int32).reshape(-1)
+    if pages.size == 0:
+        return data
+    return _zero(data, jnp.asarray(pages))
 
-    def one(path, pl):
-        if _is_pos(path) or not _is_seq(path) or pages.size == 0:
-            return pl
-        ids = jnp.asarray(pages)
-        return pl.at[:, ids].set(jnp.zeros_like(pl[:, ids]))
 
-    return jax.tree_util.tree_map_with_path(one, data)
+def _zero_impl(data, ids):
+    return _by_kind(
+        data, pos=lambda pl: pl, slot=lambda pl: pl,
+        seq=lambda pl: pl.at[:, ids].set(jnp.zeros_like(pl[:, ids])))
+
+
+_zero = jax.jit(_zero_impl, donate_argnums=(0,))
 
 
 def prefix_seed(single: Any, data: Any, pages: np.ndarray | list[int], *,
@@ -497,21 +599,28 @@ def prefix_seed(single: Any, data: Any, pages: np.ndarray | list[int], *,
     (garbage from the last matched page's tail, clipped to the staging
     extent) are overwritten by the suffix chunks before any query position
     reaches them, and causally masked until then. Slot leaves pass through
-    zero-initialised — which is why only the dense family (whole state =
-    K/V + pos) may take this path.
+    zero-initialised — which is why only the families whose whole state is
+    K/V + pos may take this path. One jitted program per staging extent and
+    padded page count.
     """
-    pages = np.asarray(pages, np.int32)
-    ids = jnp.asarray(pages)
+    pages = np.asarray(pages, np.int32).reshape(-1)
+    return _seed(single, data, _pad_pages(pages, _trash_of(data)),
+                 jnp.int32(pages.size * block), jnp.int32(resume),
+                 block=block)
 
-    def one(path, sl, dl):
-        if _is_pos(path):
-            return jnp.full_like(sl, resume)
-        if not _is_seq(path) or pages.size == 0:
-            return sl
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _seed(single, data, ids, n_rows, resume, *, block):
+    def seq(sl, dl):
         gathered = dl[:, ids]                    # (lead, n, block, *tail)
-        flat = gathered.reshape(dl.shape[0], pages.size * block,
+        flat = gathered.reshape(dl.shape[0], ids.shape[0] * block,
                                 *dl.shape[3:])
-        n_rows = min(pages.size * block, sl.shape[2])
-        return sl.at[:, 0, :n_rows].set(flat[:, :n_rows].astype(sl.dtype))
+        rows = min(flat.shape[1], sl.shape[2])
+        keep = jnp.arange(rows) < n_rows         # real pages only
+        keep = keep.reshape((1, rows) + (1,) * (sl.ndim - 3))
+        head = jnp.where(keep, flat[:, :rows].astype(sl.dtype),
+                         sl[:, 0, :rows])
+        return sl.at[:, 0, :rows].set(head)
 
-    return jax.tree_util.tree_map_with_path(one, single, data)
+    return _by_kind(single, data, seq=seq, slot=lambda sl, dl: sl,
+                    pos=lambda sl, dl: jnp.full_like(sl, resume))

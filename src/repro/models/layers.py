@@ -13,15 +13,19 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.sc_attention import sc_attention_bits_ok, sc_pv, sc_scores
 
 __all__ = ["rms_norm", "rope", "apply_rope", "apply_mrope", "flash_attention",
-           "decode_attention", "paged_decode_attention", "PagedKV", "softcap"]
+           "decode_attention", "paged_decode_attention", "PagedKV", "softcap",
+           "yarn_mscale", "yarn_correction_range", "rope_inv_freq",
+           "deinterleave"]
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
@@ -48,6 +52,59 @@ def rope(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array, 
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 · mscale · ln(scale) + 1``
+    (1 at ``scale <= 1``)."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max_pos: int,
+                          beta_fast: float, beta_slow: float
+                          ) -> tuple[int, int]:
+    """The rotary dims (of ``dim / 2``) between which YaRN's ramp runs: the
+    dims that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context, floored and ceiled and clipped to ``[0, dim - 1]``."""
+    def corr(rot):
+        return (dim * math.log(original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(corr(beta_fast)), 0),
+            min(math.ceil(corr(beta_slow)), dim - 1))
+
+
+def rope_inv_freq(dim: int, theta: float, *, yarn_factor: float = 0.0,
+                  original_max_pos: int = 4096, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """Inverse frequencies ``(dim / 2,)`` float32: the plain rope's, or with
+    ``yarn_factor`` YaRN's blend (DeepSeek's form) of the interpolated
+    ``f / factor`` below the ramp and the original ``f`` above it."""
+    extra = (1.0 / (np.float32(theta) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))))
+    extra = extra.astype(np.float32)
+    if not yarn_factor:
+        return extra
+    inter = (1.0 / (np.float32(yarn_factor) * np.float32(theta) ** (
+        np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))))
+    low, high = yarn_correction_range(dim, theta, original_max_pos,
+                                      beta_fast, beta_slow)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low), 0, 1)
+    mask = (1.0 - ramp).astype(np.float32)
+    return (inter * (1 - mask) + extra * mask).astype(np.float32)
+
+
+def deinterleave(x: jax.Array) -> jax.Array:
+    """``(..., d)`` in pair order ``(x0, x1), (x2, x3), ...`` -> the even
+    entries then the odd ones, so that :func:`apply_rope`'s half-split
+    rotation turns each original pair (DeepSeek's rope layout)."""
+    d = x.shape[-1]
+    return jnp.swapaxes(x.reshape(*x.shape[:-1], d // 2, 2), -1, -2
+                        ).reshape(x.shape)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -1,14 +1,20 @@
-"""Mixture-of-Experts FFN: group-limited GShard-style top-k routing.
+"""Mixture-of-Experts FFN: dropless per-token top-k routing.
 
-Tokens are routed within fixed-size groups so the one-hot dispatch/combine
-tensors stay ``(G, E, C)`` with ``G = router_group_size`` — the standard trick
-that keeps GShard dispatch memory bounded and shards cleanly: groups shard
-over the data axis, experts over the model axis, and GSPMD inserts the
-all-to-all at the dispatch/combine einsums.
+Every token picks its ``top_k`` experts from a float32 softmax over the
+router logits and is computed by each of them: nothing is dropped, so a
+token's output depends on the token alone, never on the batch it shares a
+step with (which is what lets a request's output be served and cached
+exactly). ``cfg.norm_topk_prob`` renormalises the chosen gates to sum to one
+(qwen3); without it they stay the softmax probabilities (DeepSeek-V2).
 
-Capacity ``C = G·top_k/E · capacity_factor``; overflow tokens drop (their
-combine weight is zero), matching GShard/Switch semantics. A load-balancing
-aux loss (Switch §2.2) is returned alongside.
+The routed experts run as one grouped matmul over the ``tokens × top_k``
+rows sorted by expert, with group sizes from the routing
+(``kernels.grouped_matmul``: the TPU kernel or ``lax.ragged_dot``), so the
+work is proportional to the rows routed. With ``cfg.use_sc_gemm`` each
+expert's matmuls go through ``sc_proj`` instead, vmapped over a padded
+dropless layout (per-expert capacity = every row). Shared experts are one
+dense gated MLP added to every token. A Switch load-balance aux loss is
+returned for training, and per-call load counters for serving.
 """
 from __future__ import annotations
 
@@ -17,13 +23,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.sc_layers import sc_proj
+from repro.kernels.grouped_matmul import grouped_matmul
 
-__all__ = ["init_moe_params", "moe_ffn", "moe_capacity"]
+__all__ = ["init_moe_params", "moe_ffn", "route", "N_STATS"]
 
-
-def moe_capacity(cfg: ModelConfig) -> int:
-    g, e = cfg.router_group_size, cfg.n_experts
-    return max(int(g * cfg.top_k / e * cfg.capacity_factor), 4)
+#: Load counters ``moe_ffn`` returns per call: (token, expert) rows computed,
+#: and the rows of the busiest expert.
+N_STATS = 2
 
 
 def init_moe_params(cfg: ModelConfig, key: jax.Array, dtype) -> dict:
@@ -51,79 +57,94 @@ def _act(name: str):
     return jax.nn.silu if name == "silu" else jax.nn.gelu
 
 
-def _expert_ffn(params: dict, xe: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Per-expert gated FFN on the dispatched tokens ``xe: (ng, E, C, d)``.
+def route(params: dict, x: jax.Array, cfg: ModelConfig):
+    """``x: (T, d)`` -> (softmax probabilities ``(T, E)``, gates and expert
+    ids of each token's top ``k``, ``(T, k)``), in float32 at full matmul
+    precision, so a near tie is broken as a float32 reference breaks it."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gate = gate / gate.sum(-1, keepdims=True)
+    return probs, gate, idx
 
-    With ``cfg.use_sc_gemm`` each expert's three matmuls route through the
-    ``sc_proj`` dispatch (vmapped over the expert axis, so every expert
-    quantizes with its own per-tensor scale), honoring ``cfg.sc_impl`` like
-    the dense layers (DESIGN.md §6).
-    """
+
+def _experts(params: dict) -> tuple:
+    """The routed experts' ``(w1, w3, w2)``, each ``(E, ...)``: the layer's
+    own, or its slice of the stacks a scan passes with a ``layer`` index."""
+    ws = tuple(params[k] for k in ("w1", "w3", "w2"))
+    if "layer" in params:
+        ws = tuple(w[params["layer"]] for w in ws)
+    return ws
+
+
+def _grouped(params: dict, rows: jax.Array, sizes: jax.Array,
+             cfg: ModelConfig) -> jax.Array:
+    """The gated expert MLP of ``rows`` sorted by expert, as grouped
+    matmuls. Under a scan (``layer`` given) the weights stay whole stacks
+    ``(G, E, ...)`` seen as ``G · E`` groups, every other layer's group
+    empty: no slice of them is copied."""
+    ws = [params[k] for k in ("w1", "w3", "w2")]
+    if "layer" in params:
+        g, e = ws[0].shape[:2]
+        ws = [w.reshape(g * e, *w.shape[2:]) for w in ws]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((g * e,), jnp.int32), sizes, (params["layer"] * e,))
     act = _act(cfg.act)
-    if cfg.use_sc_gemm:
-        ng, e, c, d = xe.shape
-        xef = xe.transpose(1, 0, 2, 3).reshape(e, ng * c, d)   # (E, rows, d)
-        dense = jax.vmap(lambda xr, w: sc_proj(xr, w, cfg))
-        h = act(dense(xef, params["w1"])) * dense(xef, params["w3"])
-        ye = dense(h, params["w2"])                             # (E, rows, d)
-        return ye.reshape(e, ng, c, d).transpose(1, 0, 2, 3)
-    h = act(jnp.einsum("gecd,edf->gecf", xe, params["w1"])) * \
-        jnp.einsum("gecd,edf->gecf", xe, params["w3"])
-    return jnp.einsum("gecf,efd->gecd", h, params["w2"])
+    h = act(grouped_matmul(rows, ws[0], sizes)) * \
+        grouped_matmul(rows, ws[1], sizes)
+    return grouped_matmul(h, ws[2], sizes)
 
 
-def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """``x: (B, S, d)`` -> (output, aux_loss). Routing in fp32."""
+def _sc_experts(params: dict, rows: jax.Array, expert: jax.Array,
+                sizes: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The grouped products through ``sc_proj``: rows laid out per expert at
+    a capacity of every row (nothing can overflow), each expert's matmuls
+    vmapped so it quantizes with its own per-tensor scale."""
+    n, d = rows.shape
+    e = cfg.n_experts
+    start = jnp.cumsum(sizes) - sizes
+    slot = jnp.arange(n) - start[expert]
+    xe = jnp.zeros((e, n, d), rows.dtype).at[expert, slot].set(rows)
+    dense = jax.vmap(lambda xr, w: sc_proj(xr, w, cfg))
+    act = _act(cfg.act)
+    w1, w3, w2 = _experts(params)
+    h = act(dense(xe, w1)) * dense(xe, w3)
+    return dense(h, w2)[expert, slot]
+
+
+def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig
+            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``x: (B, S, d)`` -> (output, aux loss, load counters ``(N_STATS,)``
+    int32)."""
     b, s, d = x.shape
-    e, c = cfg.n_experts, moe_capacity(cfg)
-    t = b * s
-    g = min(cfg.router_group_size, t)   # decode steps have few tokens
-    assert t % g == 0, f"tokens {t} % group {g}"
-    ng = t // g
-    xg = x.reshape(ng, g, d)
+    t, k, e = b * s, cfg.top_k, cfg.n_experts
+    xf = x.reshape(t, d)
+    probs, gate, idx = route(params, xf, cfg)
 
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32), params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)                     # (ng, G, E)
-
-    # --- top-k slot-by-slot dispatch with running per-expert positions
-    gates = jnp.zeros((ng, g, e), jnp.float32)
-    position = jnp.zeros((ng, g, e), jnp.int32)
-    counts = jnp.zeros((ng, 1, e), jnp.int32)
-    masked = probs
-    for _ in range(cfg.top_k):
-        idx = jnp.argmax(masked, axis=-1)                       # (ng, G)
-        onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)
-        gate = (masked * onehot).sum(-1, keepdims=True)         # chosen prob
-        pos = counts + jnp.cumsum(onehot.astype(jnp.int32), axis=1) - onehot.astype(jnp.int32)
-        keep = (pos < c) & (onehot > 0)
-        gates = gates + jnp.where(keep, gate * onehot, 0.0)
-        position = jnp.where(keep, pos, position)
-        counts = counts + onehot.astype(jnp.int32).sum(axis=1, keepdims=True)
-        masked = masked * (1.0 - onehot)                        # remove chosen
-
-    # normalize gates over the selected experts (norm_topk_prob, qwen3-style)
-    denom = jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    gates = gates / denom
-
-    # --- combine tensor (ng, G, E, C); dispatch is its support
-    pos_onehot = jax.nn.one_hot(position, c, dtype=jnp.float32)  # (ng,G,E,C)
-    combine = gates[..., None] * pos_onehot * (gates[..., None] > 0)
-    dispatch = (combine > 0).astype(xg.dtype)
-
-    xe = jnp.einsum("gtec,gtd->gecd", dispatch, xg)              # (ng,E,C,d)
-    ye = _expert_ffn(params, xe, cfg)                            # (ng,E,C,d)
-    y = jnp.einsum("gtec,gecd->gtd", combine.astype(xg.dtype), ye)
+    flat = idx.reshape(-1)                                   # (T·k,)
+    order = jnp.argsort(flat, stable=True)                   # by expert
+    expert = flat[order]
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = xf[order // k]                                    # (T·k, d)
+    if cfg.use_sc_gemm:
+        y = _sc_experts(params, rows, expert, sizes, cfg)
+    else:
+        y = _grouped(params, rows, sizes, cfg)               # (T·k, d)
+    y = y[jnp.argsort(order)].reshape(t, k, d)               # token order
+    out = jnp.einsum("tk,tkd->td", gate, y.astype(jnp.float32))
+    out = out.astype(x.dtype).reshape(b, s, d)
 
     # --- Switch load-balance aux loss: E · Σ_e f_e · P_e
-    me = probs.mean(axis=1)                                      # (ng, E)
-    top1 = jax.nn.one_hot(jnp.argmax(probs, -1), e, dtype=jnp.float32)
-    fe = top1.mean(axis=1)
-    aux = e * jnp.mean(jnp.sum(fe * me, axis=-1))
+    top1 = jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32)
+    aux = e * jnp.sum(top1.mean(axis=0) * probs.mean(axis=0))
+    stats = jnp.stack([jnp.int32(t * k), sizes.max()]).astype(jnp.int32)
 
-    out = y.reshape(b, s, d)
     if "shared" in params:
         sh = params["shared"]
         act = _act(cfg.act)
         hs = act(sc_proj(x, sh["w1"], cfg)) * sc_proj(x, sh["w3"], cfg)
         out = out + sc_proj(hs, sh["w2"], cfg)
-    return out, aux
+    return out, aux, stats

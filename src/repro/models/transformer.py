@@ -25,10 +25,11 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.sc_layers import sc_proj
 from repro.parallel.context import shard_activations
-from .layers import (PagedKV, apply_mrope, apply_rope, decode_attention,
-                     flash_attention, paged_decode_attention, rms_norm, rope,
-                     softcap)
-from .moe import init_moe_params, moe_ffn
+from .layers import (PagedKV, _gather_pages, apply_mrope, apply_rope,
+                     deinterleave, decode_attention, flash_attention,
+                     paged_decode_attention, rms_norm, rope, rope_inv_freq,
+                     softcap, yarn_mscale)
+from .moe import N_STATS, init_moe_params, moe_ffn
 
 __all__ = ["init_params", "forward_hidden", "loss_fn", "init_kv_cache",
            "decode_step", "paged_decode_step", "decode_window_step",
@@ -63,6 +64,28 @@ def _init_attn(cfg: ModelConfig, key, dtype) -> dict:
     return p
 
 
+def _init_mla(cfg: ModelConfig, key, dtype) -> dict:
+    """Latent attention weights (DeepSeek-V2, no query compression):
+    ``wq (d, H, nope + rope)``, ``wkv_a (d, rank + rope)`` giving the latent
+    ``c_kv`` and the shared rope key, ``kv_norm (rank,)``, ``wkv_b (rank, H,
+    nope + v)`` decompressing ``c_kv`` into each head's key and value, and
+    ``wo (H, v, d)``."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rp, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": (jax.random.normal(ks[0], (d, h, nope + rp)) * d ** -0.5
+               ).astype(dtype),
+        "wkv_a": (jax.random.normal(ks[1], (d, r + rp)) * d ** -0.5
+                  ).astype(dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": (jax.random.normal(ks[2], (r, h, nope + vd)) * r ** -0.5
+                  ).astype(dtype),
+        "wo": (jax.random.normal(ks[3], (h, vd, d)) * (h * vd) ** -0.5
+               ).astype(dtype),
+    }
+
+
 def _init_mlp(cfg: ModelConfig, key, dtype) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
@@ -73,17 +96,18 @@ def _init_mlp(cfg: ModelConfig, key, dtype) -> dict:
     }
 
 
-def _init_layer(cfg: ModelConfig, pos: int, key, dtype) -> dict:
+def _init_layer(cfg: ModelConfig, pos: int, key, dtype, *,
+                dense: bool = False) -> dict:
     ka, kf = jax.random.split(key)
     layer = {
         "ln1": jnp.ones((cfg.d_model,), dtype),
         "ln2": jnp.ones((cfg.d_model,), dtype),
-        "attn": _init_attn(cfg, ka, dtype),
+        "attn": (_init_mla if cfg.mla else _init_attn)(cfg, ka, dtype),
     }
     if cfg.post_norms:
         layer["ln1_post"] = jnp.ones((cfg.d_model,), dtype)
         layer["ln2_post"] = jnp.ones((cfg.d_model,), dtype)
-    if cfg.moe_at(pos):
+    if cfg.moe_at(pos) and not dense:
         layer["moe"] = init_moe_params(cfg, kf, dtype)
     else:
         layer["mlp"] = _init_mlp(cfg, kf, dtype)
@@ -94,7 +118,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     cfg.validate()
     dtype = _dtype(cfg)
     gsz = cfg.group_size
-    ngroups = cfg.n_layers // gsz
+    ngroups = cfg.scan_layers // gsz
     k_emb, k_head, k_layers = jax.random.split(key, 3)
 
     if cfg.n_codebooks:
@@ -120,6 +144,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> dict:
     gkeys = jax.random.split(k_layers, ngroups)
     stacked = jax.vmap(init_group)(gkeys)   # leaves: (ngroups, ...)
     params["layers"] = stacked
+    if cfg.first_k_dense:
+        # leading dense layers, stacked (first_k_dense, ...), run unrolled
+        # before the scan
+        lkeys = jax.random.split(jax.random.fold_in(key, 3), cfg.first_k_dense)
+        params["lead"] = jax.vmap(
+            lambda k: _init_layer(cfg, 0, k, dtype, dense=True))(lkeys)
     return params
 
 
@@ -130,11 +160,157 @@ def _project(x, w, cfg, b=None):
     return out + b if b is not None else out
 
 
+# ------------------------------------------------- latent attention (MLA)
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-1/2``, times YaRN's ``mscale(factor, mscale_all_dim)²``
+    when the rope is YaRN-scaled."""
+    scale = cfg.head_dim ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, positions) -> tuple[jax.Array, jax.Array]:
+    """cos/sin tables ``(B, S, rope / 2)`` of the (YaRN-scaled) rope."""
+    inv = jnp.asarray(rope_inv_freq(
+        cfg.qk_rope_head_dim, cfg.rope_theta, yarn_factor=cfg.yarn_factor,
+        original_max_pos=cfg.yarn_original_max_pos,
+        beta_fast=cfg.yarn_beta_fast, beta_slow=cfg.yarn_beta_slow))
+    ang = positions[..., None].astype(jnp.float32) * inv
+    m = 1.0
+    if cfg.yarn_factor:
+        m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def _einsum_f32(eq: str, a, b):
+    """``einsum`` accumulated in float32: the operands in their own type
+    where the program is lowered for a TPU, cast to float32 elsewhere (the
+    CPU has no bfloat16 x bfloat16 -> float32 dot at every shape)."""
+    return jax.lax.platform_dependent(
+        a, b,
+        tpu=lambda a, b: jnp.einsum(eq, a, b,
+                                    preferred_element_type=jnp.float32),
+        default=lambda a, b: jnp.einsum(eq, a.astype(jnp.float32),
+                                        b.astype(jnp.float32)))
+
+
+def _per_head(x, w):
+    """``x (B, T, H, i)`` times each head's ``w (i, H, o)`` -> ``(B, T, H,
+    o)``: the absorbed ``W_UK`` / ``W_UV`` products. Exact under every
+    numeric: the multiplier quantizes its activation operand per row, and
+    the absorbed operands (a head's query, its weighted latent) are not the
+    published ``c_kv W_kv_b``'s, so no SC form of them can match the
+    reference's; the reference keeps that product exact too."""
+    return _einsum_f32("bthi,iho->btho", x, w).astype(x.dtype)
+
+
+def _mla_attend(q_lat, q_pe, c, k_pe, q_pos, kv_pos, scale):
+    """Absorbed latent attention: ``q_lat (B, T, H, rank)`` and ``q_pe (B,
+    T, H, rope)`` against the latent cache ``c (B, E, rank)`` and the shared
+    rope keys ``k_pe (B, E, rope)``; a key at position ``kv_pos (B, E)``
+    is seen by a query at ``q_pos (B, T)`` at or after it. One exact
+    float32 softmax per row; returns the probability-weighted latent ``(B,
+    T, H, rank)`` in float32."""
+    s = (_einsum_f32("bthr,ber->bhte", q_lat, c)
+         + _einsum_f32("bthp,bep->bhte", q_pe, k_pe)) * scale
+    mask = kv_pos[:, None, :] <= q_pos[:, :, None]              # (B, T, E)
+    s = jnp.where(mask[:, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    # the probabilities in the cache's type: a TPU rounds a float32 operand
+    # so anyway, and no float32 copy of the latent cache is made
+    return _einsum_f32("bhte,ber->bthr", p.astype(c.dtype), c)
+
+
+def _mla_forward(p: dict, x: jax.Array, cfg: ModelConfig, *, positions,
+                 cache, cache_pos, decode_window: bool = False):
+    """Multi-head latent attention (DeepSeek-V2) over a latent cache.
+
+    A token stores ``c_kv`` (``kv_lora_rank``, RMS-normed) and its rope key
+    (``qk_rope_head_dim``, shared by every head) — no head axis — in the
+    cache's ``k`` and ``v`` leaves. Every path attends in the absorbed
+    form: the query's no-rope part is taken into the latent space through
+    each head's ``W_UK`` (the key half of ``wkv_b``), scored against
+    ``c_kv`` plus the rope dot product, the probabilities are summed over
+    ``c_kv``, and each head's ``W_UV`` then gives its value. The cache is
+    never decompressed. Cache kinds as in :func:`_attn_forward`: none,
+    ``"collect"``, a :class:`PagedKV` pool (decode), or a dense ``(c, k_pe)``
+    pair (chunked prefill at the cache's offset, one-row decode, or a
+    ``decode_window`` of rows)."""
+    b, s, d = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    q = sc_proj(x, p["wq"].reshape(d, h * cfg.head_dim), cfg
+                ).reshape(b, s, h, cfg.head_dim)
+    kv = sc_proj(x, p["wkv_a"], cfg)                        # (B, S, r + rope)
+    c = rms_norm(kv[..., :r], p["kv_norm"], eps=cfg.norm_eps)
+    cos, sin = _mla_rope(cfg, positions)
+    q_pe = apply_rope(deinterleave(q[..., nope:]), cos, sin)
+    k_pe = apply_rope(deinterleave(kv[..., r:])[:, :, None], cos, sin)[:, :, 0]
+    q_lat = _per_head(q[..., :nope],
+                      p["wkv_b"][..., :nope].transpose(2, 1, 0))  # (B, S, H, r)
+
+    if isinstance(cache, PagedKV):
+        from .cache_ops import paged_token_entry
+        cache_pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
+        entry, off = paged_token_entry(cache.tables, cache_pos,
+                                       block=cache.block)
+        bid = jnp.where(entry < 0, cache.trash, entry)
+        new_cache = PagedKV(
+            cache.k.at[bid, off].set(c[:, 0].astype(cache.k.dtype)),
+            cache.v.at[bid, off].set(k_pe[:, 0].astype(cache.v.dtype)),
+            cache.tables)
+        c_all = _gather_pages(new_cache.k, cache.tables)
+        pe_all = _gather_pages(new_cache.v, cache.tables)
+        q_pos, kv_pos = cache_pos[:, None], None
+    elif cache is not None and cache != "collect":
+        c_all, pe_all = cache
+        cache_pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
+        if decode_window or s == 1:
+            # rows at each sequence's own positions; a free slot's drifted
+            # position past the cache drops
+            rows = jnp.arange(b)[:, None]
+            wpos = cache_pos[:, None] + jnp.arange(s)[None, :]
+            c_all = c_all.at[rows, wpos].set(c.astype(c_all.dtype),
+                                             mode="drop")
+            pe_all = pe_all.at[rows, wpos].set(k_pe.astype(pe_all.dtype),
+                                               mode="drop")
+        else:
+            # a prefill chunk at the staging cache's shared offset
+            start = (jnp.zeros((), jnp.int32), cache_pos[0],
+                     jnp.zeros((), jnp.int32))
+            c_all = jax.lax.dynamic_update_slice(
+                c_all, c.astype(c_all.dtype), start)
+            pe_all = jax.lax.dynamic_update_slice(
+                pe_all, k_pe.astype(pe_all.dtype), start)
+        new_cache = (c_all, pe_all)
+        q_pos, kv_pos = positions, None
+    else:
+        # the sequence attends to itself, at its own positions
+        c_all, pe_all, q_pos, kv_pos = c, k_pe, positions, positions
+        new_cache = (c, k_pe) if cache == "collect" else None
+    if kv_pos is None:      # a cache: entry i holds position i
+        e = c_all.shape[1]
+        kv_pos = jnp.broadcast_to(jnp.arange(e, dtype=jnp.int32), (b, e))
+    o_lat = _mla_attend(q_lat.astype(c_all.dtype), q_pe.astype(pe_all.dtype),
+                        c_all, pe_all, jnp.broadcast_to(q_pos, (b, s)),
+                        kv_pos, mla_softmax_scale(cfg))
+    out = _per_head(o_lat.astype(x.dtype), p["wkv_b"][..., nope:])
+    o = sc_proj(out.reshape(b, s, h * vd), p["wo"].reshape(h * vd, d), cfg)
+    return o, new_cache
+
+
 def _attn_forward(p: dict, x: jax.Array, cfg: ModelConfig, *,
                   window: int | None, positions, mrope_positions,
                   cache: tuple | None, cache_pos,
                   canonical_positions: bool = True,
                   decode_window: bool = False) -> tuple[jax.Array, tuple | None]:
+    if cfg.mla:
+        return _mla_forward(p, x, cfg, positions=positions, cache=cache,
+                            cache_pos=cache_pos, decode_window=decode_window)
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     # one resolution point for every attention call below, so the prefill,
@@ -295,14 +471,89 @@ def _layer_forward(layer: dict, x: jax.Array, cfg: ModelConfig, pos: int, *,
 
     ff_in = rms_norm(x, layer["ln2"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
     aux = jnp.float32(0.0)
-    if cfg.moe_at(pos):
-        ff_out, aux = moe_ffn(layer["moe"], ff_in, cfg)
+    stats = jnp.zeros((N_STATS,), jnp.int32)
+    if "moe" in layer:
+        ff_out, aux, stats = moe_ffn(layer["moe"], ff_in, cfg)
     else:
         ff_out = _mlp_forward(layer["mlp"], ff_in, cfg)
     if cfg.post_norms:
         ff_out = rms_norm(ff_out, layer["ln2_post"], eps=cfg.norm_eps,
                           plus_one=cfg.norm_plus_one)
-    return x + ff_out, new_cache, aux
+    return x + ff_out, new_cache, aux, stats
+
+
+def _lead_layers(params: dict, cfg: ModelConfig, x: jax.Array, caches, **kw):
+    """The leading dense layers (``cfg.first_k_dense``), unrolled before the
+    scan. ``caches`` is None or each layer's cache argument. Returns ``(x,
+    new caches, aux)``."""
+    new, aux = [], jnp.float32(0.0)
+    for i in range(cfg.first_k_dense):
+        layer = jax.tree.map(lambda a: a[i], params["lead"])
+        x, c, a, _ = _layer_forward(layer, x, cfg, 0,
+                                    cache=None if caches is None else caches[i],
+                                    **kw)
+        new.append(c)
+        aux += a
+    return x, new, aux
+
+
+def _lead_cache(cfg: ModelConfig, cache) -> list:
+    """Each leading layer's ``(k, v)`` leaves: the leading layers' stacks
+    follow the group positions in the cache's ``k``/``v`` tuples."""
+    if not cfg.first_k_dense:
+        return []
+    lk, lv = cache.k[cfg.group_size], cache.v[cfg.group_size]
+    return [(lk[i], lv[i]) for i in range(cfg.first_k_dense)]
+
+
+def _stack_lead(new: list) -> tuple:
+    """The leading layers' new ``(k, v)`` pairs -> the ``(k stack,)``,
+    ``(v stack,)`` tails of the cache's tuples (empty without them)."""
+    if not new:
+        return (), ()
+    return ((jnp.stack([c[0] for c in new]),),
+            (jnp.stack([c[1] for c in new]),))
+
+
+#: The routed experts' weights, which the scans keep out of their per-layer
+#: slices (see :func:`_scan_params`).
+_EXPERT_LEAVES = ("w1", "w3", "w2")
+
+
+def _scan_params(layers) -> tuple:
+    """Split the stacked group params for a scan over the groups: the
+    per-group slices without the routed experts' weights, and those weights
+    as whole stacks per group position. The grouped matmul reads its
+    layer's experts straight out of the stack (``moe.moe_ffn`` with a
+    ``layer`` index): a slice of them taken inside the scan would be copied
+    in every step, about three times the expert bytes a step must read."""
+    xs, stacks = [], []
+    for pos_params in layers:
+        moe = pos_params.get("moe")
+        if moe is None:
+            xs.append(pos_params)
+            stacks.append(None)
+            continue
+        xs.append({**pos_params, "moe": {k: v for k, v in moe.items()
+                                         if k not in _EXPERT_LEAVES}})
+        stacks.append({k: moe[k] for k in _EXPERT_LEAVES})
+    return tuple(xs), stacks
+
+
+def _group_params(group_params, stacks, g) -> tuple:
+    """One group's params inside the scan, the routed experts given as
+    their stacks and the group index ``g``."""
+    return tuple(p if st is None else
+                 {**p, "moe": {**p["moe"], **st, "layer": g}}
+                 for p, st in zip(group_params, stacks))
+
+
+def _count(cache, stats):
+    """The cache's MoE load counters advanced by ``stats`` (scan outputs
+    ``(ngroups, N_STATS)``); None for a cache without them."""
+    if cache.counts is None:
+        return None
+    return cache.counts + stats.sum(axis=0).astype(cache.counts.dtype)
 
 
 def _embed_tokens(params, cfg: ModelConfig, batch: dict) -> jax.Array:
@@ -333,23 +584,31 @@ def forward_hidden(params: dict, cfg: ModelConfig, batch: dict) -> tuple[jax.Arr
     mrope_positions = batch.get("mrope_positions")
 
     gsz = cfg.group_size
+    x, _, aux_lead = _lead_layers(params, cfg, x, None, positions=positions,
+                                  mrope_positions=mrope_positions,
+                                  cache_pos=None,
+                                  canonical_positions=canonical)
 
-    def group_body(x, group_params):
+    xs, stacks = _scan_params(params["layers"])
+
+    def group_body(x, inputs):
         x = shard_activations(x)
+        group_params = _group_params(inputs[0], stacks, inputs[1])
         aux_total = jnp.float32(0.0)
         for pos in range(gsz):
-            x, _, aux = _layer_forward(group_params[pos], x, cfg, pos,
-                                       positions=positions,
-                                       mrope_positions=mrope_positions,
-                                       cache=None, cache_pos=None,
-                                       canonical_positions=canonical)
+            x, _, aux, _ = _layer_forward(group_params[pos], x, cfg, pos,
+                                          positions=positions,
+                                          mrope_positions=mrope_positions,
+                                          cache=None, cache_pos=None,
+                                          canonical_positions=canonical)
             aux_total += aux
         return x, aux_total
 
     body = jax.checkpoint(group_body) if cfg.remat else group_body
-    x, auxes = jax.lax.scan(lambda c, p: body(c, p), x, params["layers"])
+    x, auxes = jax.lax.scan(lambda c, p: body(c, p), x,
+                            (xs, jnp.arange(len(xs[0]["ln1"]))))
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
-    return x, auxes.sum()
+    return x, auxes.sum() + aux_lead
 
 
 def logits_from_hidden(params: dict, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
@@ -409,31 +668,44 @@ def prefill_step(params: dict, cfg: ModelConfig, batch: dict, *,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     mrope_positions = batch.get("mrope_positions")
     gsz = cfg.group_size
+    x, lead, _ = _lead_layers(params, cfg, x, ["collect"] * cfg.first_k_dense,
+                              positions=positions,
+                              mrope_positions=mrope_positions, cache_pos=None)
 
-    def group_body(x, group_params):
+    xs, stacks = _scan_params(params["layers"])
+
+    def group_body(x, inputs):
         x = shard_activations(x)
+        group_params = _group_params(inputs[0], stacks, inputs[1])
         ks, vs = [], []
+        stats = jnp.zeros((N_STATS,), jnp.int32)
         for pos in range(gsz):
-            x, kvc, _ = _layer_forward(group_params[pos], x, cfg, pos,
-                                       positions=positions,
-                                       mrope_positions=mrope_positions,
-                                       cache="collect", cache_pos=None)
+            x, kvc, _, st = _layer_forward(group_params[pos], x, cfg, pos,
+                                           positions=positions,
+                                           mrope_positions=mrope_positions,
+                                           cache="collect", cache_pos=None)
             ks.append(kvc[0])
             vs.append(kvc[1])
-        return x, (tuple(ks), tuple(vs))
+            stats += st
+        return x, (tuple(ks), tuple(vs), stats)
 
     body = jax.checkpoint(group_body) if cfg.remat else group_body
-    x, (ks, vs) = jax.lax.scan(lambda c, p: body(c, p), x, params["layers"])
+    x, (ks, vs, stats) = jax.lax.scan(lambda c, p: body(c, p), x,
+                                      (xs, jnp.arange(len(xs[0]["ln1"]))))
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = logits_from_hidden(params, cfg, x[:, -1:])
 
+    lk, lv = _stack_lead(lead)
+    ks, vs = ks + lk, vs + lv
     if extra_slots:
-        pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, extra_slots),
-                                    (0, 0), (0, 0)))
+        def pad(a):
+            return jnp.pad(a, [(0, 0), (0, 0), (0, extra_slots)]
+                           + [(0, 0)] * (a.ndim - 3))
         ks = tuple(pad(k) for k in ks)
         vs = tuple(pad(v) for v in vs)
-    cache = KVCache(k=ks, v=vs, pos=jnp.full((b,), s, jnp.int32))
-    return logits, cache
+    cache = KVCache(k=ks, v=vs, pos=jnp.full((b,), s, jnp.int32),
+                    counts=_init_counts(cfg))
+    return logits, cache._replace(counts=_count(cache, stats))
 
 
 def prefill_chunk_step(params: dict, cfg: ModelConfig, cache: "KVCache",
@@ -459,29 +731,15 @@ def prefill_chunk_step(params: dict, cfg: ModelConfig, cache: "KVCache",
         mrope_positions = jnp.broadcast_to(positions[None],
                                            (3, b, t)).astype(jnp.int32)
 
-    gsz = cfg.group_size
-
-    def group_body(x, inputs):
-        x = shard_activations(x)
-        group_params = inputs["params"]
-        new_k, new_v = [], []
-        for p in range(gsz):
-            x, kvc, _ = _layer_forward(
-                group_params[p], x, cfg, p,
-                positions=positions, mrope_positions=mrope_positions,
-                cache=(inputs["k"][p], inputs["v"][p]), cache_pos=pos,
-                canonical_positions=False)
-            new_k.append(kvc[0])
-            new_v.append(kvc[1])
-        return x, (tuple(new_k), tuple(new_v))
-
-    x, (ks, vs) = jax.lax.scan(
-        group_body, x,
-        {"params": params["layers"], "k": cache.k, "v": cache.v})
+    x, ks, vs, stats = _scan_cached(
+        params, cfg, cache, x, lambda k, v: (k, v), positions=positions,
+        mrope_positions=mrope_positions, cache_pos=pos,
+        canonical_positions=False, shard=True)
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
     last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
     logits = logits_from_hidden(params, cfg, last)
-    return logits, KVCache(k=ks, v=vs, pos=pos + n_valid)
+    return logits, KVCache(k=ks, v=vs, pos=pos + n_valid,
+                           counts=_count(cache, stats))
 
 
 # ------------------------------------------------------------------ decode
@@ -489,20 +747,78 @@ def prefill_chunk_step(params: dict, cfg: ModelConfig, cache: "KVCache",
 class KVCache(NamedTuple):
     """Decode cache. Slot contract (``models.cache_ops``, DESIGN.md §7):
     array leaves carry the batch/slot dimension at axis 1; ``pos`` is a
-    per-sequence ``(B,)`` int32 position vector."""
-    k: Any   # tuple over group positions of (ngroups, B, S, KV, hd)
+    per-sequence ``(B,)`` int32 position vector.
+
+    ``k``/``v`` are tuples over the scan group's positions of ``(ngroups,
+    B, S, KV, hd)`` leaves, followed, with leading dense layers, by one
+    ``(first_k_dense, B, S, ...)`` stack. Under latent attention (MLA) ``k``
+    holds each token's ``c_kv`` ``(..., S, kv_lora_rank)`` and ``v`` its
+    shared rope key ``(..., S, qk_rope_head_dim)``: no head axis.
+    ``counts`` (MoE families) is the pool-wide ``(N_STATS,)`` int32 load
+    counters the step programs advance on the device (``moe.moe_ffn``)."""
+    k: Any
     v: Any
     pos: jax.Array
+    counts: Any = None
+
+
+def _init_counts(cfg: ModelConfig):
+    return jnp.zeros((N_STATS,), jnp.int32) if cfg.n_experts else None
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: int) -> KVCache:
     dtype = _dtype(cfg)
-    ngroups = cfg.n_layers // cfg.group_size
-    kv, hd = cfg.n_kv_heads, cfg.head_dim
-    shape = (ngroups, batch, max_seq, kv, hd)
-    k = tuple(jnp.zeros(shape, dtype) for _ in range(cfg.group_size))
-    v = tuple(jnp.zeros(shape, dtype) for _ in range(cfg.group_size))
-    return KVCache(k=k, v=v, pos=jnp.zeros((batch,), jnp.int32))
+    ngroups = cfg.scan_layers // cfg.group_size
+    if cfg.mla:
+        tails = ((cfg.kv_lora_rank,), (cfg.qk_rope_head_dim,))
+    else:
+        tails = ((cfg.n_kv_heads, cfg.head_dim),) * 2
+    stacks = [ngroups] * cfg.group_size + (
+        [cfg.first_k_dense] if cfg.first_k_dense else [])
+    k = tuple(jnp.zeros((n, batch, max_seq) + tails[0], dtype)
+              for n in stacks)
+    v = tuple(jnp.zeros((n, batch, max_seq) + tails[1], dtype)
+              for n in stacks)
+    return KVCache(k=k, v=v, pos=jnp.zeros((batch,), jnp.int32),
+                   counts=_init_counts(cfg))
+
+
+def _scan_cached(params: dict, cfg: ModelConfig, cache: KVCache, x,
+                 layer_cache, *, shard: bool = False, **kw):
+    """Every layer over a cache: the leading dense layers, then the scan
+    over the groups. ``layer_cache(k_leaf, v_leaf)`` builds what
+    ``_attn_forward`` consumes for one layer; ``kw`` goes to each
+    ``_layer_forward``. Returns ``(x, k tuple, v tuple, stats)``, the
+    tuples in the cache's layout and ``stats`` the scan's ``(ngroups,
+    N_STATS)`` MoE counters."""
+    gsz = cfg.group_size
+    x, lead, _ = _lead_layers(
+        params, cfg, x, [layer_cache(k, v) for k, v in _lead_cache(cfg, cache)],
+        **kw)
+
+    xs, stacks = _scan_params(params["layers"])
+
+    def group_body(x, inputs):
+        if shard:
+            x = shard_activations(x)
+        group_params = _group_params(inputs["params"], stacks, inputs["g"])
+        new_k, new_v = [], []
+        stats = jnp.zeros((N_STATS,), jnp.int32)
+        for p in range(gsz):
+            x, kvc, _, st = _layer_forward(
+                group_params[p], x, cfg, p,
+                cache=layer_cache(inputs["k"][p], inputs["v"][p]), **kw)
+            new_k.append(kvc[0])
+            new_v.append(kvc[1])
+            stats += st
+        return x, (tuple(new_k), tuple(new_v), stats)
+
+    x, (ks, vs, stats) = jax.lax.scan(
+        group_body, x,
+        {"params": xs, "g": jnp.arange(len(xs[0]["ln1"])),
+         "k": cache.k[:gsz], "v": cache.v[:gsz]})
+    lk, lv = _stack_lead(lead)
+    return x, ks + lk, vs + lv, stats
 
 
 def _run_decode(params: dict, cfg: ModelConfig, cache: KVCache, batch: dict,
@@ -525,27 +841,13 @@ def _run_decode(params: dict, cfg: ModelConfig, cache: KVCache, batch: dict,
         mrope_positions = jnp.broadcast_to(pos[None, :, None],
                                            (3, b, 1)).astype(jnp.int32)
 
-    gsz = cfg.group_size
-
-    def group_body(x, inputs):
-        group_params = inputs["params"]
-        new_k, new_v = [], []
-        for p in range(gsz):
-            x, kvc, _ = _layer_forward(
-                group_params[p], x, cfg, p,
-                positions=positions, mrope_positions=mrope_positions,
-                cache=layer_cache(inputs["k"][p], inputs["v"][p]),
-                cache_pos=pos)
-            new_k.append(kvc[0])
-            new_v.append(kvc[1])
-        return x, (tuple(new_k), tuple(new_v))
-
-    x, (ks, vs) = jax.lax.scan(
-        group_body, x,
-        {"params": params["layers"], "k": cache.k, "v": cache.v})
+    x, ks, vs, stats = _scan_cached(
+        params, cfg, cache, x, layer_cache, positions=positions,
+        mrope_positions=mrope_positions, cache_pos=pos)
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = logits_from_hidden(params, cfg, x)
-    return logits, KVCache(k=ks, v=vs, pos=pos + 1)
+    return logits, KVCache(k=ks, v=vs, pos=pos + 1,
+                           counts=_count(cache, stats))
 
 
 def decode_step(params: dict, cfg: ModelConfig, cache: KVCache,
@@ -600,24 +902,10 @@ def decode_window_step(params: dict, cfg: ModelConfig, cache: KVCache,
         mrope_positions = jnp.broadcast_to(positions[None],
                                            (3, b, w)).astype(jnp.int32)
 
-    gsz = cfg.group_size
-
-    def group_body(x, inputs):
-        group_params = inputs["params"]
-        new_k, new_v = [], []
-        for p in range(gsz):
-            x, kvc, _ = _layer_forward(
-                group_params[p], x, cfg, p,
-                positions=positions, mrope_positions=mrope_positions,
-                cache=(inputs["k"][p], inputs["v"][p]), cache_pos=pos,
-                decode_window=True)
-            new_k.append(kvc[0])
-            new_v.append(kvc[1])
-        return x, (tuple(new_k), tuple(new_v))
-
-    x, (ks, vs) = jax.lax.scan(
-        group_body, x,
-        {"params": params["layers"], "k": cache.k, "v": cache.v})
+    x, ks, vs, stats = _scan_cached(
+        params, cfg, cache, x, lambda k, v: (k, v), positions=positions,
+        mrope_positions=mrope_positions, cache_pos=pos, decode_window=True)
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps, plus_one=cfg.norm_plus_one)
     logits = logits_from_hidden(params, cfg, x)
-    return logits, KVCache(k=ks, v=vs, pos=pos + w)
+    return logits, KVCache(k=ks, v=vs, pos=pos + w,
+                           counts=_count(cache, stats))

@@ -80,7 +80,7 @@ def fit_spec(spec: P, shape: tuple, mesh: Mesh) -> P:
 def _spec_for(path: tuple, leaf, cfg: ModelConfig, mesh: Mesh) -> P:
     keys = [_key_of(p) for p in path]
     name = keys[-1]
-    in_layers = "layers" in keys
+    in_layers = "layers" in keys or "lead" in keys   # stacked layer params
     fsdp = _fsdp_axis(mesh)
     ndim = leaf.ndim - (1 if in_layers else 0)   # strip stacked group dim
 

@@ -14,22 +14,24 @@ just loops over it.
   (default: one chunk) on the staging prompt before decoding, so admission
   never stalls batched decode for more than one chunk — the one-shot
   prefill stall this replaces is the whole-prompt forward between two
-  decode steps. On the final chunk the staging cache is truncated to the
-  exact prompt extent (``cache_ops.truncate_seq``) and admitted through
-  the same ``slot_insert`` / ``paged_insert`` path a one-shot prefill
-  uses, so pool page accounting and every PR 4 paging invariant are
-  untouched. ``prefill_mode="oneshot"`` keeps the whole-prompt
-  ``cached_prefill_step`` admission as the scheduling A/B.
-* *Prefix cache (paged + chunked + dense)*: before staging a prompt, the
-  engine consults a token-hash radix tree (``serving.prefix``, DESIGN.md
-  §12) mapping block-aligned prompt prefixes to pages already resident in
-  the pool. On a hit the matched pages are pinned, the staging cache is
+  decode steps. On the final chunk the staging cache is admitted through
+  the same ``paged_insert`` path a one-shot prefill uses, with the prompt
+  length, which writes the rows past it as zeros (one jitted admission per
+  bucket; the contiguous pool's ``slot_insert`` takes the extent cut by
+  ``cache_ops.truncate_seq``), so pool page accounting and every paging
+  invariant (DESIGN.md §8) are untouched. ``prefill_mode="oneshot"`` keeps the
+  whole-prompt ``cached_prefill_step`` admission as the scheduling A/B.
+* *Prefix cache (paged + chunked + dense or MoE)*: before staging a
+  prompt, the engine consults a token-hash radix tree (``serving.prefix``,
+  DESIGN.md §12) mapping block-aligned prompt prefixes to pages already
+  resident in the pool. On a hit the matched pages are pinned, the staging cache is
   *seeded* with their K/V and enters the chunked-prefill carry at the
   resume offset — only the divergent suffix is computed — and admission
   attaches the block table to the shared pages (copy-on-write for the
-  page holding the resume point). Sharing is gated to the dense family:
-  ssm/hybrid recurrent state lives in O(1) slot leaves the page pool
-  never captures, so a cached prefix cannot restore it.
+  page holding the resume point). Sharing is gated to the dense and MoE
+  families (``PREFIX_FAMILIES``): ssm/hybrid recurrent state lives in O(1)
+  slot leaves the page pool never captures, so a cached prefix cannot
+  restore it.
 * *Grow (paged only)*: before each decode step, every live slot's next
   write position must map to an allocated page — and be *writable*: a
   shared or prefix-retained page is copied before the first write lands
@@ -97,6 +99,12 @@ from .slots import PagedSlotPool, PoolExhausted, SlotEntry, SlotPool
 __all__ = ["Engine", "default_serving_mesh"]
 
 span = jax.profiler.TraceAnnotation
+
+#: Families whose whole per-request state is K/V (or latent) pages plus a
+#: position, and whose output for a token depends on that token's own
+#: context alone (MoE routing is dropless and per token), so a cached
+#: prefix's pages stand in exactly for recomputing it.
+PREFIX_FAMILIES = ("dense", "moe")
 
 #: The totals :meth:`Engine.counters` keeps itself (the rest it reads from
 #: the pool and the queue).
@@ -200,9 +208,9 @@ class Engine:
     ``prefix_cache=True`` (the default) shares block-aligned prompt
     prefixes across requests through a token-hash radix tree over the
     paged pool (DESIGN.md §12) — active only where it is exact: paged
-    layout, chunked prefill, dense family (the other families keep
-    recurrent state outside the page pool). ``prefix_hash_seed`` keys the
-    block hash; streams are invariant to it.
+    layout, chunked prefill, a family in ``PREFIX_FAMILIES`` (the others
+    keep recurrent state outside the page pool). ``prefix_hash_seed`` keys
+    the block hash; streams are invariant to it.
     """
 
     def __init__(self, cfg, params, *, capacity: int = 4, max_seq: int = 256,
@@ -300,11 +308,12 @@ class Engine:
                                            block=block, n_blocks=n_blocks,
                                            cache=data)
             if (prefix_cache and prefill_mode == "chunked"
-                    and cfg.family == "dense"):
+                    and cfg.family in PREFIX_FAMILIES):
                 self.prefix = PrefixCache(block=self.pool.block,
                                           seed=prefix_hash_seed,
                                           align=self.chunk)
                 self.pool.prefix = self.prefix
+                self.pool.warm_prefix_edits()
         else:
             self._decode, shardings, _ = cached_decode_step(
                 cfg, self.mesh, batch_size=capacity, seq_len=max_seq)
@@ -352,14 +361,27 @@ class Engine:
         step's rows where a caller reads a greedy row's logits);
         ``queue_pops`` and ``queue_wait_s`` (seconds from ``submit`` or a
         preemption's requeue to the pop that starts the request's prefill,
-        summed); and the speculative ``spec_rounds``,
+        summed); the speculative ``spec_rounds``,
         ``spec_drafted_tokens``, ``spec_accepted_tokens`` and
-        ``spec_emitted_tokens``."""
+        ``spec_emitted_tokens``; and for a mixture of experts
+        ``moe_routed_rows`` ((token, expert) rows the routed experts
+        computed, padding and free slots included) and
+        ``moe_max_expert_rows`` (the busiest expert's rows, summed over MoE
+        layer executions; with the first, the routing imbalance). Those two
+        are kept on the device by the step programs (the pool's
+        ``counts``) and fetched here alone; a prefill's rows count once it
+        is admitted."""
+        routed = busiest = 0
+        counts = getattr(self.pool.cache, "counts", None)
+        if counts is not None:
+            with span("engine.counters"):
+                routed, busiest = (int(c) for c in jax.device_get(counts))
         return {**self._counts, "decode_steps": self._step,
                 "cow_copies": getattr(self.pool, "n_cow", 0),
                 "prefix_reclaims": getattr(self.pool, "n_reclaimed", 0),
                 "queue_pops": self.queue.pops,
-                "queue_wait_s": self.queue.wait_s}
+                "queue_wait_s": self.queue.wait_s,
+                "moe_routed_rows": routed, "moe_max_expert_rows": busiest}
 
     @property
     def has_work(self) -> bool:
@@ -560,7 +582,11 @@ class Engine:
         self._staging = None
         req = st.entry.request
         with span("engine.admit", uid=req.uid):
-            single = cache_ops.truncate_seq(st.cache, req.prompt_len)
+            # the paged pool takes the bucket-padded staging cache and the
+            # prompt length (one admission program per bucket); the
+            # contiguous pool takes the exact extent
+            single = (st.cache if self.paged else
+                      cache_ops.truncate_seq(st.cache, req.prompt_len))
             st.entry.admitted_at = time.perf_counter()
             st.entry.admit_step = self._step
             if st.match is not None:

@@ -375,7 +375,7 @@ class PagedSlotPool:
             ids = self.prefix.reclaim(n - len(self._free_pages),
                                       self.refcount)
             if ids:
-                self.cache = cache_ops.paged_zero_pages(self.cache, ids)
+                self._zero_pages(ids)
                 self.retained.difference_update(ids)
                 self.n_reclaimed += len(ids)
                 for p in ids:
@@ -391,6 +391,28 @@ class PagedSlotPool:
         self.peak_pages = max(self.peak_pages, self.pages_in_use)
         return pages
 
+    def _table_width(self, pages) -> np.ndarray:
+        """``pages`` padded with the trash block (page ``n_blocks``) to the
+        table's width: the eviction and the zeroing then compile once per
+        pool, whatever the count of pages."""
+        out = np.full((max(self.max_blocks, len(pages)),), self.n_blocks,
+                      np.int32)
+        out[:len(pages)] = pages
+        return out
+
+    def _zero_pages(self, pages) -> None:
+        self.cache = cache_ops.paged_zero_pages(self.cache,
+                                                self._table_width(pages))
+
+    def warm_prefix_edits(self) -> None:
+        """Run the page edits only a prefix cache reaches (a reclaim's
+        zeroing, a copy-on-write) once, on the trash block alone, which no
+        read sees: they compile in set-up, not when a serving window first
+        meets page pressure."""
+        self._zero_pages([self.n_blocks])
+        self.cache = cache_ops.paged_copy_page(self.cache, self.n_blocks,
+                                               self.n_blocks)
+
     def _release_page(self, page: int) -> None:
         """Drop one reference; zero + free at refcount 0 unless the prefix
         tree retains the page warm."""
@@ -399,7 +421,7 @@ class PagedSlotPool:
             raise PrefixCacheInvariantError(
                 f"page {page} refcount went negative")
         if self.refcount[page] == 0 and page not in self.retained:
-            self.cache = cache_ops.paged_zero_pages(self.cache, [page])
+            self._zero_pages([page])
             heapq.heappush(self._free_pages, int(page))
 
     # --------------------------------------------------- prefix refcounting
@@ -453,7 +475,8 @@ class PagedSlotPool:
         """Reserve the prompt's pages and insert a prefilled B=1 cache into
         the lowest free slot. Lazy reservation: only ``ceil(prompt / block)``
         pages are taken now; decode growth allocates the rest on demand
-        (:meth:`ensure_page`)."""
+        (:meth:`ensure_page`). ``single_cache`` may be a bucket-padded
+        staging cache: rows past the prompt are written as zeros."""
         req = entry.request
         if not self._free:
             raise PoolExhausted("slot pool is full", uid=req.uid)
@@ -464,7 +487,8 @@ class PagedSlotPool:
         assert slot not in self.entries, "free-list/entries desync"
         self.tables[slot, :len(pages)] = pages
         self.cache = cache_ops.paged_insert(self.cache, single_cache, slot,
-                                            pages, block=self.block)
+                                            pages, block=self.block,
+                                            length=req.prompt_len)
         self.entries[slot] = entry
         return slot
 
@@ -498,7 +522,8 @@ class PagedSlotPool:
             self.n_cow += 1
         self.cache = cache_ops.paged_insert(self.cache, single_cache, slot,
                                             fresh, block=self.block,
-                                            start=match.resume)
+                                            start=match.resume,
+                                            length=req.prompt_len)
         self.entries[slot] = entry
         return slot
 
@@ -546,7 +571,8 @@ class PagedSlotPool:
                 f"slot {slot} eviction drove a page refcount negative")
         freed = [int(p) for p in pages.tolist()
                  if self.refcount[p] == 0 and p not in self.retained]
-        self.cache = cache_ops.paged_evict(self.cache, slot, freed)
+        self.cache = cache_ops.paged_evict(self.cache, slot,
+                                           self._table_width(freed))
         self.tables[slot, :] = -1
         for p in freed:
             heapq.heappush(self._free_pages, p)

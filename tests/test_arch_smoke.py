@@ -60,6 +60,6 @@ def test_reduced_decode_step(arch):
 
 
 def test_all_archs_present():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     families = {cfg.family for cfg in ARCHS.values()}
     assert families == {"dense", "moe", "ssm", "hybrid", "vlm", "audio"}

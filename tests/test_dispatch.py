@@ -215,8 +215,7 @@ def test_model_families_sc_impl_parity(impl):
     cases = {
         "dense": {},
         "moe": dict(d_ff=0, n_experts=4, top_k=2, moe_d_ff=32,
-                    moe_flags=(True,), router_group_size=16,
-                    capacity_factor=4.0, shared_expert_d_ff=16),
+                    moe_flags=(True,), shared_expert_d_ff=16),
         "ssm": dict(n_heads=4, n_kv_heads=1, d_ff=0, ssm_state=16,
                     ssm_headdim=16, ssm_chunk=4),
         "hybrid": dict(ssm_state=16, ssm_headdim=16, ssm_chunk=4,

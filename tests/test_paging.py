@@ -168,6 +168,32 @@ def test_evicted_pages_come_back_zeroed():
         assert float(jnp.abs(leaf).sum()) == 0.0
 
 
+def test_page_edits_compile_per_bucket_not_per_length(dense_params):
+    """Serving prompts of 16 lengths compiles one admission program per
+    prompt bucket and one eviction (its page list padded to the table's
+    width): the page edits never compile per prompt length or count of
+    pages, and the streams are what the contiguous pool serves."""
+    from repro.launch.steps import bucket_for
+    from repro.models import cache_ops
+    cfg = CASES[0]
+    lengths = list(range(9, 41, 2))
+    reqs = [Request(uid=f"r{n}", prompt=_prompt(cfg, n, n), max_new_tokens=3)
+            for n in lengths]
+    eng = Engine(cfg, dense_params, capacity=2, max_seq=48, block=8,
+                 chunk=8, prefix_cache=False)
+    n_insert = cache_ops._insert._cache_size()
+    n_evict = cache_ops._evict._cache_size()
+    out = eng.run(reqs)
+    buckets = {bucket_for(n, eng.buckets) for n in lengths}
+    assert cache_ops._insert._cache_size() - n_insert <= len(buckets)
+    assert cache_ops._evict._cache_size() - n_evict <= 1
+    ref = Engine(cfg, dense_params, capacity=2, max_seq=48, paged=False,
+                 chunk=8).run([Request(uid=r.uid, prompt=r.prompt,
+                                       max_new_tokens=3) for r in reqs])
+    for a, b in zip(out, ref):
+        np.testing.assert_array_equal(a.tokens, b.tokens)
+
+
 # ------------------------------------------- bookkeeping invariant fuzz
 
 def _check_invariants(pool: PagedSlotPool):

@@ -22,8 +22,7 @@ CASES = [
     _cfg("dense"),
     _cfg("dense", qkv_bias=True, qk_norm=True),
     _cfg("audio", n_kv_heads=4, vocab_size=64, n_codebooks=4),
-    _cfg("moe", d_ff=0, n_experts=4, top_k=2, moe_d_ff=32, moe_flags=(True,),
-         router_group_size=16, capacity_factor=4.0),
+    _cfg("moe", d_ff=0, n_experts=4, top_k=2, moe_d_ff=32, moe_flags=(True,)),
     _cfg("ssm", n_kv_heads=1, d_ff=0, ssm_state=16, ssm_headdim=16, ssm_chunk=4),
     _cfg("hybrid", n_kv_heads=4, ssm_state=16, ssm_headdim=16, ssm_chunk=4,
          shared_attn_every=2, n_layers=4),

@@ -74,3 +74,64 @@ def test_flash_kernel_compiles(one_chip, d):
                                                bk=512),
         q, kv, kv)
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh(one_chip):
+    """A 1x1 serving mesh on the described chip."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array([one_chip._device]).reshape(1, 1), ("data", "model"))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dsv2lite_offline_steps_compile(v5e_mesh, program):
+    """The ``dsv2lite-offline`` cell's step programs at full width
+    (deepseek-v2-lite, 8 of 27 layers, all 64 experts, bf16): the decode
+    step over 64 slots of 40 pages of 64 tokens, and a 512-row prefill
+    chunk into the 2048-token bucket. The routed experts lower to the
+    grouped-matmul kernel; the arguments must fit the chip's 16 GB."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs.registry import ARCHS
+    from repro.launch.steps import (build_chunked_prefill_step,
+                                    build_paged_decode_step)
+    from repro.models import bind, cache_ops
+
+    cfg = dataclasses.replace(ARCHS["deepseek-v2-lite"], n_layers=8).validate()
+    one = NamedSharding(v5e_mesh, P())
+    m = bind(cfg)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+    if program == "decode":
+        step, _, params = build_paged_decode_step(
+            cfg, v5e_mesh, capacity=64, block=64, n_blocks=64 * 40,
+            max_blocks=40)
+        cache = jax.eval_shape(lambda: cache_ops.paged_init(
+            m.init_cache, 64, 64 * 40, 64))
+        args = (abstract(params), abstract(cache),
+                jax.ShapeDtypeStruct((64, 40), jnp.int32, sharding=one),
+                {"tokens": jax.ShapeDtypeStruct((64, 1), jnp.int32,
+                                                sharding=one)})
+    else:
+        step, _, params = build_chunked_prefill_step(cfg, v5e_mesh,
+                                                     seq_len=2048, chunk=512)
+        cache = jax.eval_shape(lambda: m.init_cache(1, 2048))
+        args = (abstract(params), abstract(cache),
+                {"tokens": jax.ShapeDtypeStruct((1, 512), jnp.int32,
+                                                sharding=one),
+                 "n_valid": jax.ShapeDtypeStruct((1,), jnp.int32,
+                                                 sharding=one)})
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print(f"\ndsv2lite-offline {program}: arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, outputs "
+          f"{mem.output_size_in_bytes / 2**30:.3f} GiB, aliased "
+          f"{mem.alias_size_in_bytes / 2**30:.3f} GiB")
+    assert "gmm" in compiled.as_text()
+    assert mem.argument_size_in_bytes < 16e9
